@@ -3,8 +3,8 @@
 PR 1 established the policy; PR 4 leaned on it (AxisType meshes); nothing
 enforced it.  Outside ``repro/dist/compat.py`` this rule bans:
 
-  * version-dependent attributes: ``AxisType``, ``TPUCompilerParams``,
-    ``log_compiles`` reached through any jax module alias
+  * ``AxisType`` and ``log_compiles`` reached through any jax module
+    alias (mesh axis types and compile-log parsing have one home each)
   * raw ``jax.__version__`` / ``jaxlib.__version__`` inspection
   * ``jax.make_mesh(...)`` (use ``repro.dist.compat.make_mesh``)
   * ``hasattr`` / ``getattr`` probes on jax modules
@@ -28,7 +28,6 @@ EXEMPT_SUFFIX = "repro/dist/compat.py"
 
 VERSIONED_ATTRS = {
     "AxisType": "jax.sharding.AxisType is version-dependent",
-    "TPUCompilerParams": "pltpu.TPUCompilerParams moved across versions",
     "log_compiles": "jax.log_compiles is a moving debug API",
 }
 VERSION_STRINGS = {"jax.__version__", "jaxlib.__version__"}

@@ -1,21 +1,22 @@
-"""pallas-kernel: panel budgets, index idiom, compiler-params routing.
+"""pallas-kernel: panel budgets, ref-index idiom, compiler params.
 
 Applies to any module importing ``jax.experimental.pallas``.  Three checks:
 
-  * **int-index loads** — every element of a ``pl.load``/``pl.store``
-    index tuple must be ``pl.ds(...)``/``pl.dslice(...)`` or
-    ``slice(...)``; bare ints/expressions are rejected by older pallas
-    lowerings (the exact pattern that bit PR 1's first kernel)
+  * **ref indexing** — kernels read and write refs by indexing
+    (``x = x_ref[i]``, ``o_ref[0, r] += v``).  ``pl.load``/``pl.store``
+    no longer exist in the pinned JAX and are rejected; and a slice in a
+    ``*_ref[...]`` index must have static (literal) bounds, because a
+    ref slice with a computed start fails at trace time — a dynamic
+    window is written ``pl.ds(start, size)``
   * **resident-panel budget** — a kernel whose out BlockSpec index_map
     ignores one or more grid axes keeps that output panel resident in
     VMEM across the ignored axes (it accumulates).  Such a kernel must be
     dispatched behind a static VMEM budget check (a caller referencing
     ``_panel_overflow`` / ``VMEM_PANEL_BYTES``, with a ref fallback —
     the PR 5 contract in kernels/ops.py)
-  * **compiler-params routing** — ``pallas_call`` should pass
-    ``compiler_params=tpu_compiler_params(...)`` (the dist/compat shim),
-    never a raw version-dependent params class, so kernels stay runnable
-    across the CI JAX pins
+  * **compiler params** — ``pallas_call`` should pass
+    ``compiler_params=pltpu.CompilerParams(dimension_semantics=...)``;
+    a call without them is a warning
 """
 from __future__ import annotations
 
@@ -34,7 +35,8 @@ from ..framework import (
 )
 
 PALLAS_MODULE = "jax.experimental.pallas"
-ALLOWED_INDEX_CALLS = ("ds", "dslice", "slice")
+REMOVED_REF_CALLS = (".load", ".store")
+REF_SUFFIX = "_ref"
 BUDGET_MARKERS = {"_panel_overflow", "VMEM_PANEL_BYTES"}
 
 
@@ -84,68 +86,60 @@ class _KernelInfo:
 @register
 class PallasKernel(Rule):
     name = "pallas-kernel"
-    description = ("VMEM panel budgets, pl.ds index idiom, and "
-                   "compiler-params routing in Pallas kernels")
+    description = ("VMEM panel budgets, ref-index idiom, and compiler "
+                   "params in Pallas kernels")
 
     def check_file(self, src, ctx):
         aliases = import_aliases(src.tree)
         if not _uses_pallas(aliases):
             return
         for node in ast.walk(src.tree):
+            if isinstance(node, ast.Subscript):
+                yield from self._check_ref_index(node, src)
+                continue
             if not isinstance(node, ast.Call):
                 continue
             full = resolve_alias(dotted(node.func), aliases)
-            if full.endswith((".load", ".store")) and \
+            if full.endswith(REMOVED_REF_CALLS) and \
                     full.startswith(PALLAS_MODULE):
-                yield from self._check_index(node, src)
+                yield Finding(
+                    self.name, src.rel, node.lineno, node.col_offset,
+                    f"{dotted(node.func)}() is gone from the pinned JAX; "
+                    f"index the ref instead (x = x_ref[idx], "
+                    f"o_ref[idx] = v)", ERROR)
             elif full.endswith("pallas_call"):
-                yield from self._check_compiler_params(node, src, aliases)
+                yield from self._check_compiler_params(node, src)
 
-    # -- int-index idiom --------------------------------------------------
+    # -- ref-index idiom --------------------------------------------------
 
-    def _check_index(self, call: ast.Call, src):
-        if len(call.args) < 2:
+    def _check_ref_index(self, sub: ast.Subscript, src):
+        name = dotted(sub.value) or ""
+        if not name.endswith(REF_SUFFIX):
             return
-        idx = call.args[1]
-        if isinstance(idx, ast.Name):
-            idx = _resolve_local_tuple(call, idx.id) or idx
-        if isinstance(idx, ast.Name):
-            return                        # opaque index var: cannot judge
-        elements = idx.elts if isinstance(idx, (ast.Tuple, ast.List)) \
-            else [idx]
+        idx = sub.slice
+        elements = idx.elts if isinstance(idx, ast.Tuple) else [idx]
         for e in elements:
-            if isinstance(e, ast.Call):
-                d = dotted(e.func) or ""
-                if d.split(".")[-1] in ALLOWED_INDEX_CALLS:
-                    continue
+            if not isinstance(e, ast.Slice):
+                continue
+            bounds = [b for b in (e.lower, e.upper, e.step) if b is not None]
+            if all(isinstance(b, ast.Constant) for b in bounds):
+                continue
             yield Finding(
                 self.name, src.rel, e.lineno, e.col_offset,
-                f"pl.load/pl.store index element '{_snippet(e)}' is not "
-                f"pl.ds(...)/slice(...) — bare int indices are rejected "
-                f"by older pallas lowerings; wrap in pl.ds(i, 1)", ERROR)
+                f"ref slice '{_snippet(e)}' on {name} has computed bounds "
+                f"— a ref slice must be static; write the dynamic window "
+                f"as pl.ds(start, size)", ERROR)
 
     # -- compiler params --------------------------------------------------
 
-    def _check_compiler_params(self, call: ast.Call, src, aliases):
-        for kw in call.keywords:
-            if kw.arg != "compiler_params":
-                continue
-            if isinstance(kw.value, ast.Call):
-                d = dotted(kw.value.func) or ""
-                if d.split(".")[-1] == "tpu_compiler_params":
-                    return
-            yield Finding(
-                self.name, src.rel, kw.value.lineno, kw.value.col_offset,
-                "compiler_params should come from "
-                "repro.dist.compat.tpu_compiler_params(...) so the kernel "
-                "survives params-class renames across JAX pins", ERROR)
+    def _check_compiler_params(self, call: ast.Call, src):
+        if any(kw.arg == "compiler_params" for kw in call.keywords):
             return
         # no compiler_params at all: acceptable for interpret-only kernels
         yield Finding(
             self.name, src.rel, call.lineno, call.col_offset,
             "pallas_call without compiler_params — pass "
-            "tpu_compiler_params(dimension_semantics=...) from dist/compat",
-            WARNING)
+            "pltpu.CompilerParams(dimension_semantics=...)", WARNING)
 
     # -- resident-panel budget (cross-file) -------------------------------
 
@@ -255,24 +249,6 @@ class PallasKernel(Rule):
                 if (body_names | body_attrs) & BUDGET_MARKERS:
                     gated = True
         return gated, callers
-
-
-def _resolve_local_tuple(call: ast.AST, name: str):
-    """Find `name = (...)` in the enclosing function of `call`."""
-    from ..framework import parent
-    node = call
-    while node is not None and not isinstance(
-            node, (ast.FunctionDef, ast.AsyncFunctionDef, ast.Module)):
-        node = parent(node)
-    if node is None:
-        return None
-    for stmt in ast.walk(node):
-        if isinstance(stmt, ast.Assign) and len(stmt.targets) == 1 and \
-                isinstance(stmt.targets[0], ast.Name) and \
-                stmt.targets[0].id == name and \
-                isinstance(stmt.value, (ast.Tuple, ast.List)):
-            return stmt.value
-    return None
 
 
 def _snippet(node: ast.AST) -> str:

@@ -4,10 +4,10 @@ Everything about *where tensors live and how devices talk* is this
 package; the factorization math (core/), kernels (kernels/) and drivers
 (launch/) stay distribution-blind.  Module map:
 
-  compat.py   — version-tolerance layer for moved JAX APIs (AxisType-aware
-                ``make_mesh``, pallas compiler-params class rename,
-                ``cost_analysis()`` list-vs-dict normalization).  The only
-                module allowed to feature-detect JAX.
+  compat.py   — backend-tolerance layer over the pinned JAX (Auto-axis
+                ``make_mesh``, donation only where XLA aliases, compile-log
+                parsing, memory-analysis normalization).  The only module
+                allowed to probe JAX surfaces.
   sharding.py — placement rules + collectives: logical-axis specs
                 (``logical_spec`` / ``constrain`` / ``param_specs`` /
                 ``opt_state_specs`` / ``cache_specs``) for the LM

@@ -1,33 +1,23 @@
-"""Version-tolerance layer for the JAX APIs whose surface moved under us.
+"""Backend-tolerance layer for the JAX surfaces this repo wraps.
 
-This module is the ONLY place allowed to feature-detect JAX versions; the
-rest of the codebase imports the tolerant wrappers and stays version-blind.
-Policy (recorded in CHANGES.md): every raw use of an API that exists in
-some-but-not-all supported JAX versions must be routed through here, with
-the newest spelling tried first and a semantically identical fallback for
-older releases.  Currently shimmed:
+The repo targets one JAX release (the version pinned in CI); this module
+holds the few wrappers whose behaviour differs by *backend* (TPU vs the
+CPU test container) or that normalize a JAX result into the shape the
+rest of the code reads.  Outside this module, code stays backend-blind:
 
-  * ``jax.sharding.AxisType`` / ``jax.make_mesh(..., axis_types=...)`` —
-    axis types landed after 0.4.x; ``make_mesh`` here degrades to the
-    positional form (all axes default to auto sharding-propagation, which
-    is exactly what ``AxisType.Auto`` requests).
-  * ``pltpu.CompilerParams`` — renamed from ``TPUCompilerParams``;
-    ``tpu_compiler_params`` returns whichever class exists (or ``None``
-    when running a JAX build without the TPU pallas backend).
-  * ``compiled.cost_analysis()`` — returns a dict on newer JAX, a
-    one-dict-per-program list on older; ``cost_analysis_dict`` normalizes
-    both to a flat {metric: value} dict.
-  * ``compiled.memory_analysis()`` — the stats object gained
-    ``peak_memory_in_bytes`` only on newer releases (0.4.x lacks it) and
-    is ``None`` on some backends; ``program_memory`` normalizes to one
-    byte-breakdown dict or ``None``, never a silent 0.
-  * ``device.memory_stats()`` — allocator watermarks exist on TPU/GPU,
-    return ``None`` (or raise) on CPU; ``device_memory_stats`` flattens
-    to a plain int dict, ``{}`` when unsupported.
-  * ``jax.log_compiles`` message formats — the logger text that announces
-    an XLA compilation has been reworded across releases;
-    ``capture_compiles`` parses the known spellings so the compile-count
-    CI guard (scripts/check_compiles.py) stays version-blind.
+  * ``donating_jit`` — buffer donation only where XLA implements it.
+  * ``make_mesh`` — ``jax.make_mesh`` with every axis ``AxisType.Auto``
+    (GSPMD propagation decides), the sharding model the engine's
+    ``shard_map`` programs are written for; ``jax.make_mesh`` alone
+    defaults to explicit axes.
+  * ``capture_compiles`` — counts XLA compilations from the
+    ``jax.log_compiles`` log lines, so the compile-count CI guard
+    (scripts/check_compiles.py) has one parser.
+  * ``program_memory`` — ``compiled.memory_analysis()`` as one byte
+    breakdown, ``None`` when the backend offers none.
+  * ``device_memory_stats`` — allocator watermarks exist on TPU and
+    return ``None`` on CPU; flattened to a plain int dict, ``{}`` when
+    unsupported.
 """
 from __future__ import annotations
 
@@ -41,17 +31,11 @@ import jax
 import jax.sharding
 
 __all__ = [
-    "AXIS_TYPE",
-    "HAS_AXIS_TYPE",
-    "axis_types_kwargs",
     "capture_compiles",
-    "cost_analysis_dict",
     "device_memory_stats",
     "donating_jit",
-    "drain_effects",
     "make_mesh",
     "program_memory",
-    "tpu_compiler_params",
 ]
 
 # Backends where XLA implements input-output aliasing.  Donating on CPU
@@ -71,9 +55,9 @@ def donating_jit(fun, *, donate_argnums: Sequence[int] = (),
     not).  Two things make this a compat concern rather than a plain
     ``donate_argnums=``:
 
-      * CPU (and some older backends) do not implement aliasing — XLA
-        warns "Some donated buffers were not usable" / "Donation is not
-        implemented" on every call site.  The CI contract is that those
+      * CPU does not implement aliasing — XLA warns "Some donated
+        buffers were not usable" / "Donation is not implemented" on every
+        call site.  The CI contract is that those
         warnings stay CLEAN, so the shim resolves the backend lazily (at
         first call, never at import) and only enables donation where it
         works.
@@ -95,69 +79,21 @@ def donating_jit(fun, *, donate_argnums: Sequence[int] = (),
 
     return wrapper
 
-# jax.sharding.AxisType (Auto/Explicit/Manual) does not exist on 0.4.x.
-AXIS_TYPE = getattr(jax.sharding, "AxisType", None)
-HAS_AXIS_TYPE = AXIS_TYPE is not None
-
-
-def axis_types_kwargs(n_axes: int) -> dict:
-    """``{"axis_types": (AxisType.Auto,) * n}`` when supported, else ``{}``.
-
-    Auto is the pre-AxisType behaviour (GSPMD propagation decides), so
-    omitting the kwarg on old JAX is semantically identical.
-    """
-    if not HAS_AXIS_TYPE:
-        return {}
-    return {"axis_types": (AXIS_TYPE.Auto,) * n_axes}
-
 
 def make_mesh(axis_shapes: Sequence[int], axis_names: Sequence[str], *,
               devices: Sequence | None = None) -> jax.sharding.Mesh:
-    """``jax.make_mesh`` that works with or without AxisType support.
+    """``jax.make_mesh`` with every axis ``AxisType.Auto``.
 
     All mesh construction in this repo goes through here (or through
-    ``launch.mesh``, which delegates here) — no raw ``AxisType`` imports
-    outside this module.
+    ``launch.mesh``, which delegates here).
     """
-    kwargs: dict[str, Any] = {}
-    if devices is not None:
-        kwargs["devices"] = devices
-    if HAS_AXIS_TYPE:
-        try:
-            return jax.make_mesh(axis_shapes, axis_names,
-                                 **axis_types_kwargs(len(axis_names)),
-                                 **kwargs)
-        except TypeError:
-            # AxisType exists but this make_mesh predates the kwarg.
-            pass
-    return jax.make_mesh(axis_shapes, axis_names, **kwargs)
+    auto = (jax.sharding.AxisType.Auto,) * len(axis_names)
+    return jax.make_mesh(axis_shapes, axis_names, axis_types=auto,
+                         devices=devices)
 
 
-def tpu_compiler_params(**kwargs):
-    """Build pallas-TPU compiler params under either class name.
-
-    Accepts the ``CompilerParams``/``TPUCompilerParams`` fields
-    (``dimension_semantics=...`` et al.); returns ``None`` when no TPU
-    pallas backend is importable, which ``pl.pallas_call`` accepts.
-    """
-    try:
-        from jax.experimental.pallas import tpu as pltpu
-    except ImportError:                                   # pragma: no cover
-        return None
-    cls = getattr(pltpu, "CompilerParams", None) \
-        or getattr(pltpu, "TPUCompilerParams", None)
-    if cls is None:                                       # pragma: no cover
-        return None
-    return cls(**kwargs)
-
-
-# "Finished XLA compilation of jit(_grid_members) in 0.1 sec" (current)
-# vs "Finished XLA compilation of _grid_members in 0.1 sec" (older).
-_FINISHED_RE = re.compile(
-    r"Finished XLA compilation of (?:jit\()?([^)\s]+)\)? in")
-# The pxla announcement line, stable for much longer; used as the fallback
-# when a JAX release drops/rewords the "Finished" line.
-_COMPILING_RE = re.compile(r"^Compiling ([^\s]+) with global shapes")
+# "Finished XLA compilation of jit(_grid_members) in 0.1 sec"
+_FINISHED_RE = re.compile(r"Finished XLA compilation of jit\(([^)\s]+)\) in")
 
 
 class CompileLog:
@@ -167,12 +103,7 @@ class CompileLog:
     ``count()`` filters by name so guards can target specific programs)."""
 
     def __init__(self):
-        self.finished: list[str] = []
-        self.compiling: list[str] = []
-
-    @property
-    def events(self) -> list[str]:
-        return self.finished if self.finished else self.compiling
+        self.events: list[str] = []
 
     def count(self, *names: str) -> int:
         """Number of compilations of the named traced functions; with no
@@ -187,14 +118,13 @@ def capture_compiles(sink=None):
     """Record every XLA compilation in the block as a ``CompileLog``.
 
     Implemented on ``jax.log_compiles`` + a logging handler rather than
-    any private counter, and tolerant of the message rewordings across
-    JAX releases (see module docstring) — the one place the compile-count
-    CI guard touches a version-dependent surface.
+    any private counter — the one place the compile-count CI guard parses
+    JAX's log wording.
 
     ``sink(program, kind)`` is additionally called on every match with
-    kind "finished" or "compiling" — the live-event side channel the
-    tracer uses (``obs.Tracer.compile_event`` has this signature).  Sink
-    exceptions are swallowed: telemetry must never fail a compile.
+    kind "finished" — the live-event side channel the tracer uses
+    (``obs.Tracer.compile_event`` has this signature).  Sink exceptions
+    are swallowed: telemetry must never fail a compile.
     """
     log = CompileLog()
 
@@ -207,16 +137,10 @@ def capture_compiles(sink=None):
 
     class _Handler(logging.Handler):
         def emit(self, record: logging.LogRecord) -> None:
-            msg = record.getMessage()
-            m = _FINISHED_RE.search(msg)
+            m = _FINISHED_RE.search(record.getMessage())
             if m:
-                log.finished.append(m.group(1))
+                log.events.append(m.group(1))
                 _notify(m.group(1), "finished")
-                return
-            m = _COMPILING_RE.match(msg)
-            if m:
-                log.compiling.append(m.group(1))
-                _notify(m.group(1), "compiling")
 
     handler = _Handler(level=logging.DEBUG)
     logger = logging.getLogger("jax")
@@ -239,30 +163,21 @@ def capture_compiles(sink=None):
         logger.propagate = old_propagate
 
 
-def drain_effects() -> None:
-    """Block until pending jax effects (``jax.debug.callback`` et al.) have
-    run on the host — readers of the obs metrics buffer call this before
-    snapshotting.  No-op on pins without ``jax.effects_barrier``."""
-    barrier = getattr(jax, "effects_barrier", None)
-    if barrier is not None:
-        barrier()
-
-
 def program_memory(compiled) -> dict[str, Any] | None:
-    """Normalize ``compiled.memory_analysis()`` across JAX pins.
-
-    Returns one byte-breakdown dict::
+    """``compiled.memory_analysis()`` as one byte-breakdown dict::
 
         {"argument": int, "output": int, "temp": int, "alias": int,
-         "peak": int, "total": int, "peak_estimated": bool}
+         "peak": int, "total": int}
 
-    where ``total = argument + output + temp - alias`` and ``peak`` is the
-    backend's ``peak_memory_in_bytes`` when the pin exposes it (newer JAX)
-    or that total with ``peak_estimated=True`` when it does not (0.4.x
-    ships ``CompiledMemoryStats`` without the peak field).  Returns
-    ``None`` when the backend offers no memory analysis at all — callers
-    must treat that as "unknown", never as 0 bytes (the silent-zero
-    ``getattr(mem, ..., 0)`` default this shim replaces).
+    where ``total = argument + output + temp - alias``.  ``peak`` is the
+    bytes the program holds at once while it runs: the larger of the
+    backend's ``peak_memory_in_bytes`` and ``total``.  The temp arena is
+    one allocation that lives for the whole execution, so every byte of
+    ``total`` is resident together; the backend's own figure adds to it
+    on TPU (it measures above ``total``) but on CPU it is a liveness
+    estimate that can fall below ``temp`` alone.  Returns ``None`` when
+    the backend offers no memory analysis at all — callers must treat
+    that as "unknown", never as 0 bytes.
     """
     try:
         mem = compiled.memory_analysis()
@@ -283,20 +198,17 @@ def program_memory(compiled) -> dict[str, Any] | None:
     arg, out, temp = arg or 0, out or 0, temp or 0
     alias = _field("alias_size_in_bytes") or 0
     total = arg + out + temp - alias
-    peak = _field("peak_memory_in_bytes")
-    estimated = peak is None
+    peak = max(_field("peak_memory_in_bytes") or 0, total)
     return {"argument": arg, "output": out, "temp": temp, "alias": alias,
-            "peak": total if estimated else peak, "total": total,
-            "peak_estimated": estimated}
+            "peak": peak, "total": total}
 
 
 def device_memory_stats(device=None) -> dict[str, int]:
     """Allocator statistics of one device as a flat int dict.
 
     TPU/GPU backends report ``bytes_in_use`` / ``peak_bytes_in_use`` et
-    al.; CPU returns ``None`` (or older pins raise) — normalized here to
-    ``{}`` so callers can record "no device watermark" instead of
-    crashing or inventing zeros.
+    al.; CPU returns ``None`` — normalized here to ``{}`` so callers can
+    record "no device watermark" instead of crashing or inventing zeros.
     """
     try:
         dev = device if device is not None else jax.local_devices()[0]
@@ -307,27 +219,3 @@ def device_memory_stats(device=None) -> dict[str, int]:
         return {}
     return {str(k): int(v) for k, v in stats.items()
             if isinstance(v, (int, float))}
-
-
-def cost_analysis_dict(analysis) -> dict[str, float]:
-    """Normalize ``compiled.cost_analysis()`` across JAX versions.
-
-    Newer JAX returns one flat dict; older returns a list with one dict
-    per program (summed here); some backends return ``None``.  Indexing
-    the raw result with a string is exactly the version-compat bug class
-    this repo bans — call this instead.
-    """
-    if analysis is None:
-        return {}
-    if isinstance(analysis, dict):
-        return dict(analysis)
-    if isinstance(analysis, (list, tuple)):
-        out: dict[str, float] = {}
-        for prog in analysis:
-            if not prog:
-                continue
-            for key, val in prog.items():
-                if isinstance(val, (int, float)):
-                    out[key] = out.get(key, 0.0) + float(val)
-        return out
-    return {}

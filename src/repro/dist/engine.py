@@ -43,7 +43,7 @@ from typing import Callable
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 # the sanitizer and obs.metrics are dependency-light (jax + numpy, never
@@ -379,7 +379,7 @@ def make_mu_step(mesh: Mesh, cfg: DistRescalConfig, *,
             local_step, mesh=mesh,
             in_specs=(x_spec, a_spec, r_spec),
             out_specs=(a_spec, r_spec),
-            check_rep=False)
+            check_vma=False)
         return jax.jit(sharded)
 
     # ---- bcsr ----
@@ -401,7 +401,7 @@ def make_mu_step(mesh: Mesh, cfg: DistRescalConfig, *,
         local_bcsr, mesh=mesh,
         in_specs=(x_spec, i_spec, i_spec, a_spec, r_spec),
         out_specs=(a_spec, r_spec),
-        check_rep=False)
+        check_vma=False)
     return jax.jit(sharded)
 
 
@@ -448,7 +448,7 @@ def make_dist_error(mesh: Mesh) -> Callable:
     sharded = shard_map(
         lambda Xl, Ai, R: local_rel_error(Xl, Ai, R), mesh=mesh,
         in_specs=(x_spec, a_spec, r_spec), out_specs=P(),
-        check_rep=False)
+        check_vma=False)
     return jax.jit(sharded)
 
 

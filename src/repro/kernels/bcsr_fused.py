@@ -14,32 +14,40 @@ over the stored blocks and materializes an (m, nnzb, bs, k) product
 intermediate in HBM before each reduction.  X's stored blocks are by far
 the largest operand, so at sparse-RESCAL shapes the memory-roofline term
 is ~2 * bytes(stored blocks) + 2 * the intermediate; this kernel tiles
-each stored block through VMEM **once**, computes both (bs, k) tile
-products on the MXU, and accumulates them straight into two VMEM-resident
-(nb, bs, k) output panels — no HBM intermediate at all.
+each stored block through VMEM **once**, computes both tile products on
+the MXU, and accumulates them straight into two VMEM-resident output
+panels — no HBM intermediate at all.
+
+Layout: the factor operands and the output panels are kept k-major,
+(nb, k, bs), so the lane axis is the 128-wide block side and only k is
+padded (to the 8-row sublane tile).  The (nb, bs, k) layout would pad k
+to 128 lanes: at k = 10 that is 12.8x the VMEM, which the chip's compiler
+refuses at real widths.  The wrapper transposes in and out.
 
 Grid: (m, nnzb).  Per step (t, z):
     data : (bs, bs)       stored block z of slice t
-    b1   : (bs, k)        row-block `cols[z]` of B1   (gathered via prefetch)
-    b2   : (bs, k)        row-block `rows[z]` of B2   (gathered via prefetch)
-    xa   : (nb, bs, k)    full output panel of slice t; row `rows[z]`
-                          accumulates data @ b1
-    xtb  : (nb, bs, k)    full output panel of slice t; row `cols[z]`
-                          accumulates data^T @ b2
+    b1   : (k, bs)        block `cols[z]` of B1^T   (gathered via prefetch)
+    b2   : (k, bs)        block `rows[z]` of B2^T   (gathered via prefetch)
+    xa   : (nb, k, bs)    full output panel of slice t; block `rows[z]`
+                          accumulates (data @ b1)^T = b1 @ data^T
+    xtb  : (nb, k, bs)    full output panel of slice t; block `cols[z]`
+                          accumulates (data^T @ b2)^T = b2 @ data
 
 Both output windows are constant per t (revisits consecutive — the pallas
 pipelining requirement) and are zeroed at z == 0, which is what makes the
 empty-block-row guarantee *kernel-side*: rows that own no stored block
 come out exact zero, with no "every block-row stores >= 1 block"
-precondition (unlike kernels/bcsr_spmm.py, whose per-row output windows
-leave untouched rows undefined).  io.partition's front-padded ShardedBCSR
-shards (all-zero padding blocks at coordinates (0, 0)) and the masked
-cross-k step's zero-column fixed point therefore stay sound on this path.
+precondition.  io.partition's front-padded ShardedBCSR shards (all-zero
+padding blocks at coordinates (0, 0)) and the masked cross-k step's
+zero-column fixed point therefore stay sound on this path.
 
-VMEM: the two resident panels cost 2 * nb * bs * k * itemsize; ops.py
-falls back to the jnp oracle when that exceeds the panel budget
-(panelizing the output like fused_bilinear's xtb window is a ROADMAP
-follow-on).
+Products run at fp32 contract precision (``Precision.HIGHEST``), so the
+kernel agrees with an fp32 reference to f32 accumulation error.
+
+VMEM: each resident panel costs nb * roundup(k, 8) * bs * itemsize per
+buffer; ops.py falls back to the jnp oracle when the panels exceed the
+panel budget (panelizing the output like fused_bilinear's xtb window is a
+ROADMAP follow-on).
 """
 from __future__ import annotations
 
@@ -50,9 +58,12 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.dist.compat import tpu_compiler_params
-
 from repro.core.sparse import BCSR
+
+HIGHEST = jax.lax.Precision.HIGHEST
+# (k, bs) x (bs, bs) contractions: A @ B^T and A @ B
+NT = (((1,), (1,)), ((), ()))
+NN = (((1,), (0,)), ((), ()))
 
 
 def _kernel(rows_ref, cols_ref, data_ref, b1_ref, b2_ref, xa_ref, xtb_ref):
@@ -66,19 +77,26 @@ def _kernel(rows_ref, cols_ref, data_ref, b1_ref, b2_ref, xa_ref, xtb_ref):
         xtb_ref[0] = jnp.zeros_like(xtb_ref[0])
 
     blk = data_ref[0, 0]                               # (bs, bs), read ONCE
-    part_a = jnp.dot(blk, b1_ref[0],
-                     preferred_element_type=jnp.float32)
-    part_t = jnp.dot(blk.T, b2_ref[0],
-                     preferred_element_type=jnp.float32)
+    part_a = jax.lax.dot_general(b1_ref[0], blk, NT, precision=HIGHEST,
+                                 preferred_element_type=jnp.float32)
+    part_t = jax.lax.dot_general(b2_ref[0], blk, NN, precision=HIGHEST,
+                                 preferred_element_type=jnp.float32)
+    xa_ref[0, rows_ref[z]] += part_a.astype(xa_ref.dtype)
+    xtb_ref[0, cols_ref[z]] += part_t.astype(xtb_ref.dtype)
 
-    # leading dims indexed with ds(start, 1), not bare ints: integer
-    # indices in pl.load/store tuples are rejected by older pallas
-    idx_a = (pl.ds(0, 1), pl.ds(rows_ref[z], 1), slice(None), slice(None))
-    pl.store(xa_ref, idx_a, pl.load(xa_ref, idx_a)
-             + part_a[None, None].astype(xa_ref.dtype))
-    idx_t = (pl.ds(0, 1), pl.ds(cols_ref[z], 1), slice(None), slice(None))
-    pl.store(xtb_ref, idx_t, pl.load(xtb_ref, idx_t)
-             + part_t[None, None].astype(xtb_ref.dtype))
+
+def to_kmajor(B: jax.Array, nb: int, bs: int) -> jax.Array:
+    """(n, k) factor -> (nb, k, bs) blocks, zero-padding n to nb * bs."""
+    n, k = B.shape
+    if nb * bs != n:
+        B = jnp.pad(B, ((0, nb * bs - n), (0, 0)))
+    return B.reshape(nb, bs, k).transpose(0, 2, 1)
+
+
+def from_kmajor(out: jax.Array, n: int) -> jax.Array:
+    """(m, nb, k, bs) panels -> (m, n, k), cropping the padded tail."""
+    m, nb, k, bs = out.shape
+    return out.transpose(0, 1, 3, 2).reshape(m, nb * bs, k)[:, :n]
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -100,37 +118,31 @@ def bcsr_xa_xta(sp: BCSR, B1: jax.Array, B2: jax.Array, *,
     if nnzb == 0:
         z = jnp.zeros((m, sp.n, k), B1.dtype)
         return z, z
-    if nb * bs != sp.n:
-        pad = ((0, nb * bs - sp.n), (0, 0))
-        B1 = jnp.pad(B1, pad)
-        B2 = jnp.pad(B2, pad)
-    B1b = B1.reshape(nb, bs, k)
-    B2b = B2.reshape(nb, bs, k)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(m, nnzb),
         in_specs=[
             pl.BlockSpec((1, 1, bs, bs), lambda t, z, rows, cols: (t, z, 0, 0)),
-            pl.BlockSpec((1, bs, k), lambda t, z, rows, cols: (cols[z], 0, 0)),
-            pl.BlockSpec((1, bs, k), lambda t, z, rows, cols: (rows[z], 0, 0)),
+            pl.BlockSpec((1, k, bs), lambda t, z, rows, cols: (cols[z], 0, 0)),
+            pl.BlockSpec((1, k, bs), lambda t, z, rows, cols: (rows[z], 0, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, nb, bs, k), lambda t, z, rows, cols: (t, 0, 0, 0)),
-            pl.BlockSpec((1, nb, bs, k), lambda t, z, rows, cols: (t, 0, 0, 0)),
+            pl.BlockSpec((1, nb, k, bs), lambda t, z, rows, cols: (t, 0, 0, 0)),
+            pl.BlockSpec((1, nb, k, bs), lambda t, z, rows, cols: (t, 0, 0, 0)),
         ],
     )
     xa, xtb = pl.pallas_call(
         _kernel,
         grid_spec=grid_spec,
         out_shape=[
-            jax.ShapeDtypeStruct((m, nb, bs, k), B1.dtype),
-            jax.ShapeDtypeStruct((m, nb, bs, k), B2.dtype),
+            jax.ShapeDtypeStruct((m, nb, k, bs), B1.dtype),
+            jax.ShapeDtypeStruct((m, nb, k, bs), B2.dtype),
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
         interpret=interpret,
         name="bcsr_xa_xta",
-    )(sp.block_rows, sp.block_cols, sp.data, B1b, B2b)
-    return (xa.reshape(m, nb * bs, k)[:, :sp.n],
-            xtb.reshape(m, nb * bs, k)[:, :sp.n])
+    )(sp.block_rows, sp.block_cols, sp.data, to_kmajor(B1, nb, bs),
+      to_kmajor(B2, nb, bs))
+    return from_kmajor(xa, sp.n), from_kmajor(xtb, sp.n)

@@ -10,9 +10,13 @@ O(m * delta * n^2 * k) sparse bound on hardware that hates gather/scatter.
 
 Grid: (m, nnzb).  Per step (t, z):
     data : (bs, bs)     stored block z of slice t
-    b    : (bs, k)      row-block `cols[z]` of B    (gathered via prefetch)
-    out  : (nb, bs, k)  full output panel of slice t, zeroed at z == 0;
-                        row `rows[z]` accumulates the tile product
+    b    : (k, bs)      block `cols[z]` of B^T   (gathered via prefetch)
+    out  : (nb, k, bs)  full output panel of slice t, zeroed at z == 0;
+                        block `rows[z]` accumulates b @ data^T, the
+                        transpose of the (bs, k) tile product
+
+Operands and panel are k-major (nb, k, bs) for the same lane-padding
+reason as kernels/bcsr_fused.py, whose layout helpers this kernel shares.
 
 The panel-resident output (window constant per t, so revisits are
 consecutive) is what makes the empty-block-row guarantee KERNEL-side:
@@ -20,8 +24,8 @@ block-rows that own no stored block come out exact zero, with no
 "every block-row stores >= 1 block" precondition — the soundness contract
 io.partition's front-padded shards rely on (ISSUE 5; the per-row
 (bs, k)-window variant this replaces left untouched rows undefined).
-VMEM: the panel costs nb * bs * k * itemsize; ops.py falls back to the
-jnp oracle past the panel budget.
+VMEM: the panel costs nb * roundup(k, 8) * bs * itemsize per buffer;
+ops.py falls back to the jnp oracle past the panel budget.
 """
 from __future__ import annotations
 
@@ -32,9 +36,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.dist.compat import tpu_compiler_params
-
 from repro.core.sparse import BCSR
+
+from .bcsr_fused import HIGHEST, NT, from_kmajor, to_kmajor
 
 
 def _kernel(rows_ref, cols_ref, data_ref, b_ref, out_ref):
@@ -46,13 +50,10 @@ def _kernel(rows_ref, cols_ref, data_ref, b_ref, out_ref):
     def _():
         out_ref[0] = jnp.zeros_like(out_ref[0])
 
-    part = jnp.dot(data_ref[0, 0], b_ref[0],
-                   preferred_element_type=jnp.float32)
-    # leading dims indexed with ds(start, 1), not bare ints: integer
-    # indices in pl.load/store tuples are rejected by older pallas
-    idx = (pl.ds(0, 1), pl.ds(rows_ref[z], 1), slice(None), slice(None))
-    pl.store(out_ref, idx, pl.load(out_ref, idx)
-             + part[None, None].astype(out_ref.dtype))
+    part = jax.lax.dot_general(b_ref[0], data_ref[0, 0], NT,
+                               precision=HIGHEST,
+                               preferred_element_type=jnp.float32)
+    out_ref[0, rows_ref[z]] += part.astype(out_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -72,27 +73,24 @@ def bcsr_spmm(sp: BCSR, B: jax.Array, *, interpret: bool = False
     k = B.shape[1]
     if nnzb == 0:
         return jnp.zeros((m, sp.n, k), B.dtype)
-    if nb * bs != sp.n:
-        B = jnp.pad(B, ((0, nb * bs - sp.n), (0, 0)))
-    Bb = B.reshape(nb, bs, k)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(m, nnzb),
         in_specs=[
             pl.BlockSpec((1, 1, bs, bs), lambda t, z, rows, cols: (t, z, 0, 0)),
-            pl.BlockSpec((1, bs, k), lambda t, z, rows, cols: (cols[z], 0, 0)),
+            pl.BlockSpec((1, k, bs), lambda t, z, rows, cols: (cols[z], 0, 0)),
         ],
         out_specs=pl.BlockSpec(
-            (1, nb, bs, k), lambda t, z, rows, cols: (t, 0, 0, 0)),
+            (1, nb, k, bs), lambda t, z, rows, cols: (t, 0, 0, 0)),
     )
     out = pl.pallas_call(
         _kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((m, nb, bs, k), B.dtype),
-        compiler_params=tpu_compiler_params(
+        out_shape=jax.ShapeDtypeStruct((m, nb, k, bs), B.dtype),
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
         interpret=interpret,
         name="bcsr_spmm",
-    )(sp.block_rows, sp.block_cols, sp.data, Bb)
-    return out.reshape(m, nb * bs, k)[:, :sp.n]
+    )(sp.block_rows, sp.block_cols, sp.data, to_kmajor(B, nb, bs))
+    return from_kmajor(out, sp.n)

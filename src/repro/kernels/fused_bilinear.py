@@ -32,7 +32,9 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from repro.dist.compat import tpu_compiler_params
+from jax.experimental.pallas import tpu as pltpu
+
+HIGHEST = jax.lax.Precision.HIGHEST
 
 DEFAULT_BM = 256
 DEFAULT_BN = 256
@@ -41,14 +43,14 @@ DEFAULT_BN = 256
 def _kernel(x_ref, b1_ref, b2_ref, xa_ref, xtb_ref):
     i = pl.program_id(1)
     j = pl.program_id(2)
-    nj = pl.num_programs(2)
 
     x = x_ref[0]                                   # (bm, bn)
     b1 = b1_ref[...]                               # (bn, k)
     b2 = b2_ref[0]                                 # (bm, k)
 
     # ---- XA row panel: init on first column block, then accumulate ----
-    part_xa = jnp.dot(x, b1, preferred_element_type=jnp.float32)
+    part_xa = jnp.dot(x, b1, precision=HIGHEST,
+                      preferred_element_type=jnp.float32)
 
     @pl.when(j == 0)
     def _():
@@ -63,15 +65,11 @@ def _kernel(x_ref, b1_ref, b2_ref, xa_ref, xtb_ref):
     def _():
         xtb_ref[0] = jnp.zeros_like(xtb_ref[0])
 
-    part_xtb = jnp.dot(x.T, b2, preferred_element_type=jnp.float32)
+    part_xtb = jnp.dot(x.T, b2, precision=HIGHEST,
+                       preferred_element_type=jnp.float32)
     bn = x.shape[1]
-    # leading dim indexed with ds(0, 1), not a bare int: integer indices in
-    # pl.load/store tuples are rejected by older pallas releases
-    idx = (pl.ds(0, 1), pl.ds(j * bn, bn), slice(None))
-    cur = pl.load(xtb_ref, idx)
-    pl.store(xtb_ref, idx,
-             cur + part_xtb[None].astype(xtb_ref.dtype))
-    del nj
+    rows = pl.ds(pl.multiple_of(j * bn, bn), bn)
+    xtb_ref[0, rows] += part_xtb.astype(xtb_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("bm", "bn", "interpret"))
@@ -103,7 +101,7 @@ def fused_xa_xtb(X: jax.Array, B1: jax.Array, B2: jax.Array,
             jax.ShapeDtypeStruct((m, n1, k), X.dtype),
             jax.ShapeDtypeStruct((m, n2, k), X.dtype),
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary", "arbitrary")),
         interpret=interpret,
         name="fused_xa_xtb",

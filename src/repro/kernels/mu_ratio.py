@@ -19,7 +19,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.dist.compat import tpu_compiler_params
 
 DEFAULT_BM = 512
 
@@ -51,7 +50,7 @@ def mu_update_a(A: jax.Array, Num: jax.Array, S: jax.Array,
         ],
         out_specs=pl.BlockSpec((bm, k), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((n, k), A.dtype),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
         name="mu_update_a",

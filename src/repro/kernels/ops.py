@@ -7,14 +7,15 @@ impl selection:
   "ref"       — pure-jnp oracle
 
 `fused_xa_xtb` additionally panelizes the n2 axis so the kernel's xtb VMEM
-window (n2_panel * k * 4B, double-buffered) stays under the budget.
+window (n2_panel * roundup(k, 128) * 4B, double-buffered) stays under the
+budget.
 
-Fallback telemetry: every budget-driven pallas->ref downgrade runs through
-`_note_fallback`, which bumps a module counter (`kernel_fallbacks()`) and —
-when a tracer is installed — emits a `kernel/fallback` instant carrying the
-budget arithmetic.  Dispatch happens at Python trace time, so the telemetry
-adds nothing to the compiled programs and the untraced build stays
-bit-identical.
+Fallback telemetry: every pallas->ref downgrade (a VMEM budget overflow or
+a degenerate tiling) runs through `_note_fallback`, which bumps a module
+counter (`kernel_fallbacks()`) and — when a tracer is installed — emits a
+`kernel/fallback` instant carrying the budget arithmetic.  Dispatch
+happens at Python trace time, so the telemetry adds nothing to the
+compiled programs and the untraced build stays bit-identical.
 """
 from __future__ import annotations
 
@@ -50,7 +51,7 @@ _n_fallbacks = 0
 
 
 def kernel_fallbacks() -> int:
-    """Process-lifetime count of budget-driven pallas->oracle fallbacks.
+    """Process-lifetime count of pallas->oracle fallbacks.
     The scheduler diffs this around each unit to attribute fallbacks."""
     return _n_fallbacks
 
@@ -85,6 +86,13 @@ def _dispatch(kernel: str, impl: str, *, cpu_impl: str = "ref") -> str:
     return impl
 
 
+_LANES, _SUBLANES = 128, 8
+
+
+def _round_up(x: int, tile: int) -> int:
+    return -(-x // tile) * tile
+
+
 def _largest_tile(n: int, cap: int) -> int:
     """Largest divisor of n that is <= cap (the kernel requires exact
     tiling of both X axes)."""
@@ -110,8 +118,11 @@ def fused_xa_xtb(X, B1, B2, *, impl: str = "auto", bm: int = 256,
     if impl == "pallas" and min(bm, bn) < 8:
         # degenerate tiling (e.g. prime shard side) loses MXU sublane
         # alignment — the jnp oracle beats a 1-wide pallas grid
+        _note_fallback("fused_xa_xtb", bm * bn * X.dtype.itemsize)
         return _ref.ref_fused_xa_xtb(X, B1, B2)
-    panel = max(bn, (VMEM_PANEL_BYTES // max(k * 4, 1)) // bn * bn)
+    # the (n2, k) xtb window pads k to whole 128-wide lane tiles in VMEM
+    panel = max(bn, (VMEM_PANEL_BYTES // (_round_up(k, _LANES) * 4))
+                // bn * bn)
     if n2 <= panel:
         return _fused_pallas(X, B1, B2, bm=bm, bn=bn, interpret=interpret)
     # panelize columns: XA sums partials, XTB concatenates panels
@@ -136,13 +147,15 @@ def mu_update_a(A, Num, S, eps: float = 1e-16, *, impl: str = "auto",
 
 
 def _panel_bytes(sp: BCSR, k: int, dtype, n_panels: int) -> int:
-    """VMEM-resident bytes of the BCSR kernels' (nb, bs, k) output
-    panel(s)."""
-    return n_panels * sp.nblocks * sp.bs * k * jnp.dtype(dtype).itemsize
+    """VMEM-resident bytes of the BCSR kernels' k-major (nb, k, bs) output
+    panel(s), one buffer each: bs fills the lanes, k is padded to the
+    8-row sublane tile."""
+    return (n_panels * sp.nblocks * _round_up(k, _SUBLANES) * sp.bs
+            * jnp.dtype(dtype).itemsize)
 
 
 def _panel_overflow(sp: BCSR, k: int, dtype, n_panels: int) -> bool:
-    """True when the BCSR kernels' VMEM-resident (nb, bs, k) output
+    """True when the BCSR kernels' VMEM-resident (nb, k, bs) output
     panel(s) exceed the panel budget (panelized outputs are a ROADMAP
     follow-on; until then the jnp oracle takes over)."""
     return _panel_bytes(sp, k, dtype, n_panels) > VMEM_PANEL_BYTES

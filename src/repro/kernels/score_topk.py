@@ -41,9 +41,10 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from repro.dist.compat import tpu_compiler_params
+from jax.experimental.pallas import tpu as pltpu
 
 DEFAULT_PN = 2048
+HIGHEST = jax.lax.Precision.HIGHEST
 _LANE = 128
 
 
@@ -84,7 +85,8 @@ def _kernel(v_ref, a_ref, s_ref, i_ref, *, n: int, pn: int, topk: int):
 
     v = v_ref[...]                                     # (b, k)
     a = a_ref[...]                                     # (pn, k)
-    sp = jnp.dot(v, a.T, preferred_element_type=jnp.float32)   # (b, pn)
+    sp = jnp.dot(v, a.T, precision=HIGHEST,
+                 preferred_element_type=jnp.float32)           # (b, pn)
     b = sp.shape[0]
     gidx = p * pn + jax.lax.broadcasted_iota(jnp.int32, (b, pn), 1)
     sp = jnp.where(gidx < n, sp, -jnp.inf)             # mask the pad tail
@@ -126,7 +128,7 @@ def score_topk(V: jax.Array, A: jax.Array, *, topk: int,
             jax.ShapeDtypeStruct((b, topk), jnp.float32),
             jax.ShapeDtypeStruct((b, topk), jnp.int32),
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
         name="score_topk",
@@ -154,7 +156,8 @@ def score_topk_stream(V: jax.Array, A: jax.Array, *, topk: int,
     def body(carry, xs):
         run_s, run_i = carry
         panel, p = xs
-        sp = jnp.dot(Vf, panel.T, preferred_element_type=jnp.float32)
+        sp = jnp.dot(Vf, panel.T, precision=HIGHEST,
+                     preferred_element_type=jnp.float32)
         gidx = jnp.broadcast_to(p * pn + base, sp.shape)
         sp = jnp.where(gidx < n, sp, -jnp.inf)
         cand_s = jnp.concatenate([run_s, sp], axis=1)

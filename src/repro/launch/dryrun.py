@@ -177,7 +177,7 @@ def run_cell(arch: str, shape: str, *, multi_pod: bool = False,
     compiled = lowered.compile()
     compile_s = time.time() - t0
     cost = hlo_costs.xla_cost_analysis(compiled)
-    # normalized across JAX pins (dist.compat); None = backend reported no
+    # normalized by dist.compat; None = backend reported no
     # memory analysis — surfaced loudly below, never claimed as 0 bytes
     mem = compat.program_memory(compiled)
     hlo = compiled.as_text()
@@ -277,9 +277,8 @@ def main():
             f.write(js)
     print(js)
     if not stats.get("skipped") and stats.get("memory") is not None:
-        est = "~" if stats["memory"].get("peak_estimated") else ""
         print(f"\nmemory/device: {stats['memory']['total']/2**30:.2f} GiB, "
-              f"peak {est}{stats['memory']['peak']/2**30:.2f} GiB "
+              f"peak {stats['memory']['peak']/2**30:.2f} GiB "
               f"(fits 16 GiB: {stats['memory']['fits_16gib']})",
               file=sys.stderr)
 

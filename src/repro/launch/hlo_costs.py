@@ -28,17 +28,11 @@ from __future__ import annotations
 import re
 from dataclasses import dataclass, field
 
-from repro.dist.compat import cost_analysis_dict
-
 
 def xla_cost_analysis(compiled) -> dict[str, float]:
-    """XLA's own HloCostAnalysis as a flat dict, version-normalized.
-
-    ``compiled.cost_analysis()`` returns a list of per-program dicts on
-    older JAX and a single dict on newer — never index the raw result
-    with a string; call this.
-    """
-    return cost_analysis_dict(compiled.cost_analysis())
+    """XLA's own HloCostAnalysis as a flat dict (``{}`` where the backend
+    offers none)."""
+    return dict(compiled.cost_analysis() or {})
 
 _DTYPE_BYTES = {
     "f64": 8, "f32": 4, "f16": 2, "bf16": 2, "f8e4m3fn": 1, "f8e5m2": 1,

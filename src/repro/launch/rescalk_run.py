@@ -43,6 +43,7 @@ import jax
 import numpy as np
 
 from repro.data.synthetic import synthetic_rescal
+from repro.launch.compile_cache import enable_compile_cache
 from repro.selection import (CRITERIA, RescalkConfig, SweepInterrupted,
                              SweepScheduler)
 
@@ -305,11 +306,10 @@ def _write_trace_artifacts(trace_dir, tracer, buf, report, operand, args):
     README "Observability" documents and scripts/check_trace.py validates)."""
     import os
 
-    from repro.dist.compat import drain_effects
     from repro.obs import costs as obs_costs
 
     # drain in-flight debug callbacks so metrics.npz sees every iteration
-    drain_effects()
+    jax.effects_barrier()
     tracer.export_chrome(os.path.join(trace_dir, "trace_chrome.json"))
     buf.save_npz(os.path.join(trace_dir, "metrics.npz"))
     parts = [tracer.summarize(), "", buf.summarize()]
@@ -336,6 +336,7 @@ def _write_trace_artifacts(trace_dir, tracer, buf, report, operand, args):
 
 
 def main():
+    enable_compile_cache()
     args = build_parser().parse_args()
     if args.fault_plan is not None:
         # installed before the tracer so every fault/inject instant of

@@ -32,6 +32,8 @@ import time
 
 import numpy as np
 
+from repro.launch.compile_cache import enable_compile_cache
+
 
 def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
@@ -134,6 +136,7 @@ def _run(args):
 
 
 def main():
+    enable_compile_cache()
     args = build_parser().parse_args()
     if args.trace is None:
         _run(args)

@@ -324,10 +324,9 @@ class MemoryLedger:
                 if not e:
                     lines.append(f"{k:>4} {'(no memory analysis)':>38}")
                     continue
-                est = "~" if e.get("peak_estimated") else " "
                 lines.append(
                     f"{k:>4} {e['argument'] / 2**20:>9.3f} "
                     f"{e['output'] / 2**20:>9.3f} "
                     f"{e['temp'] / 2**20:>9.3f} "
-                    f"{est}{e['peak'] / 2**20:>8.3f}")
+                    f"{e['peak'] / 2**20:>9.3f}")
         return "\n".join(lines)

@@ -348,7 +348,7 @@ def make_mesh_ensemble_bcsr(mesh, *, k: int, n_pad: int, m: int, r_run: int,
     holds only its (m, nnzb_loc, bs, bs) blocks; perturbation multiplies
     the stored blocks shard-locally (zero padding blocks stay zero), so
     neither the global tensor nor any member copy of it ever exists."""
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from repro.core.sparse import BCSR
     from repro.dist import sharding as sh
     from repro.dist.engine import (DistRescalConfig, get_mu_iter,
@@ -409,7 +409,7 @@ def make_mesh_ensemble_bcsr(mesh, *, k: int, n_pad: int, m: int, r_run: int,
         local, mesh=mesh,
         in_specs=(x_spec, i_spec, i_spec, mspecs["keys"], mspecs["ids"]),
         out_specs=(mspecs["A"], mspecs["R"], mspecs["err"]),
-        check_rep=False)
+        check_vma=False)
     return jax.jit(sharded)
 
 
@@ -438,7 +438,7 @@ def make_mesh_ensemble(mesh, *, k: int, n: int, m: int, r_run: int,
     bit-identical to the host reference; replacing it with per-shard init
     is a ROADMAP open item for exascale n.
     """
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from repro.dist import sharding as sh
     from repro.dist.engine import (DistRescalConfig, get_mu_iter,
                                    local_normalize, local_rel_error)
@@ -488,7 +488,7 @@ def make_mesh_ensemble(mesh, *, k: int, n: int, m: int, r_run: int,
         local, mesh=mesh,
         in_specs=(specs["X"], specs["keys"], specs["ids"]),
         out_specs=(specs["A"], specs["R"], specs["err"]),
-        check_rep=False)
+        check_vma=False)
     return jax.jit(sharded)
 
 
@@ -629,7 +629,7 @@ def make_mesh_grid_ensemble(mesh, *, operand: str, k_max: int, n: int,
     stays shard-local (``perturb_shard`` keyed by member id q + linear grid
     index), i.e. noise is bit-identical to the per-k mesh ensemble's, which
     is what makes grid-vs-per-k mesh parity exactly testable."""
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from repro.core.sparse import BCSR
     from repro.dist import sharding as sh
     from repro.dist.engine import (DistRescalConfig, get_mu_iter,
@@ -713,7 +713,7 @@ def make_mesh_grid_ensemble(mesh, *, operand: str, k_max: int, n: int,
         in_specs = (x_spec, i_spec, i_spec) + cell_specs
 
     sharded = shard_map(local, mesh=mesh, in_specs=in_specs,
-                        out_specs=out_specs, check_rep=False)
+                        out_specs=out_specs, check_vma=False)
     return jax.jit(sharded)
 
 

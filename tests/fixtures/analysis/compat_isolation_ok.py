@@ -1,7 +1,7 @@
 """Near miss: same surface shapes, but every probe targets non-jax
 objects and versioned APIs come through the compat shim."""
 import jax
-from repro.dist.compat import make_mesh, tpu_compiler_params
+from repro.dist.compat import make_mesh, program_memory
 
 
 def make_grid(cfg):
@@ -21,4 +21,4 @@ except ImportError:
     tomllib = None
 
 
-PARAMS = tpu_compiler_params
+MEMORY = program_memory

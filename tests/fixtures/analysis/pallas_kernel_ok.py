@@ -1,17 +1,18 @@
-"""Near miss: the same kernel shape done right — pl.ds everywhere
-(including through a local index variable), every grid axis used by the
-out index_map, and compiler params from the compat shim."""
+"""Near miss: the same kernel shape done right — refs read and written by
+indexing, dynamic windows through pl.ds (static slices and scalar indices
+stay plain), every grid axis used by the out index_map, and compiler
+params passed."""
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-from repro.dist.compat import tpu_compiler_params
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _copy_kernel(x_ref, o_ref):
-    idx = (pl.ds(0, 1), pl.ds(0, 128))
-    v = pl.load(x_ref, idx)
-    pl.store(o_ref, (pl.ds(0, 1), pl.ds(0, 128)), v)
+    j = pl.program_id(1)
+    v = x_ref[0, pl.ds(j * 128, 128)]
+    o_ref[0, 0:128] = v
+    o_ref[:, :] += x_ref[...]
 
 
 def copy(x):
@@ -22,6 +23,6 @@ def copy(x):
         in_specs=[pl.BlockSpec((1, 128), lambda i, j: (i, j))],
         out_specs=pl.BlockSpec((1, 128), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
     )(x)

@@ -288,7 +288,7 @@ def cache_shapes_tree(cfg):
 
 def check_ef_psum():
     from repro.optim import compression
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     mesh = compat.make_mesh((8,), ("data",))
     key = jax.random.PRNGKey(0)
     g_global = jax.random.normal(key, (8, 128))
@@ -298,7 +298,7 @@ def check_ef_psum():
 
     f = jax.jit(shard_map(local, mesh=mesh,
                           in_specs=(P("data"), P("data")),
-                          out_specs=(P(), P("data")), check_rep=False))
+                          out_specs=(P(), P("data")), check_vma=False))
     err = jnp.zeros((8, 128))
     exact_mean = g_global.mean(0)
     total_sent = jnp.zeros((128,))

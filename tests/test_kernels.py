@@ -50,6 +50,20 @@ class TestFusedBilinear:
         np.testing.assert_allclose(xa, xa_r, rtol=2e-4, atol=1e-5)
         np.testing.assert_allclose(xtb, xtb_r, rtol=2e-4, atol=1e-5)
 
+    def test_degenerate_tile_fallback_is_counted(self, key):
+        """A shard side with no tile >= 8 takes the oracle, and the
+        downgrade is counted like every other fallback."""
+        import repro.kernels.ops as ops
+        X = jax.random.uniform(key, (2, 4, 4))
+        B1 = jax.random.uniform(key, (4, 3))
+        B2 = jax.random.uniform(key, (2, 4, 3))
+        n0 = ops.kernel_fallbacks()
+        xa, xtb = fused_xa_xtb(X, B1, B2, impl="pallas")
+        assert ops.kernel_fallbacks() == n0 + 1
+        xa_r, xtb_r = ref.ref_fused_xa_xtb(X, B1, B2)
+        np.testing.assert_array_equal(xa, xa_r)
+        np.testing.assert_array_equal(xtb, xtb_r)
+
 
 class TestMuRatio:
     @pytest.mark.parametrize("n,k,bm", [(256, 8, 128), (512, 16, 256),
