@@ -8,4 +8,5 @@ from . import (  # noqa: F401
     recompile_hazard,
     resilience_seams,
     sanitizer_coverage,
+    scope_coverage,
 )

@@ -64,7 +64,8 @@ def gram(A: jax.Array) -> jax.Array:
 def update_R(X: jax.Array, A: jax.Array, R: jax.Array, G: jax.Array,
              eps: float = EPS_DEFAULT) -> jax.Array:
     """R_t <- R_t * (A^T X_t A) / (G R_t G + eps), all t at once."""
-    XA = jnp.einsum("mij,jk->mik", X, A)          # (m, n, k)
+    with jax.named_scope("products"):
+        XA = jnp.einsum("mij,jk->mik", X, A)      # (m, n, k)
     ATXA = jnp.einsum("ia,mib->mab", A, XA)        # (m, k, k)
     deno = jnp.einsum("ab,mbc,cd->mad", G, R, G)   # (m, k, k)
     return R * ATXA / (deno + eps)
@@ -77,8 +78,9 @@ def update_A(X: jax.Array, A: jax.Array, R: jax.Array, G: jax.Array,
       NumA  = sum_t X_t A R_t^T + X_t^T A R_t
       DenoA = A @ sum_t (R_t G R_t^T + R_t^T G R_t)
     """
-    XA = jnp.einsum("mij,jk->mik", X, A)           # (m, n, k)
-    XTA = jnp.einsum("mji,jk->mik", X, A)          # (m, n, k)
+    with jax.named_scope("products"):
+        XA = jnp.einsum("mij,jk->mik", X, A)       # (m, n, k)
+        XTA = jnp.einsum("mji,jk->mik", X, A)      # (m, n, k)
     num = (jnp.einsum("mia,msa->is", XA, R)
            + jnp.einsum("mia,mas->is", XTA, R))    # (n, k)
     S = (jnp.einsum("mab,bc,mdc->ad", R, G, R)
@@ -86,6 +88,7 @@ def update_A(X: jax.Array, A: jax.Array, R: jax.Array, G: jax.Array,
     return A * num / (A @ S + eps)
 
 
+@jax.named_scope("mu")
 def mu_step_batched(X: jax.Array, state: RescalState,
                     eps: float = EPS_DEFAULT,
                     sanitize: bool = False,
@@ -105,6 +108,7 @@ def mu_step_batched(X: jax.Array, state: RescalState,
     return RescalState(A=A, R=R, step=state.step + 1)
 
 
+@jax.named_scope("mu")
 def mu_step_sliced(X: jax.Array, state: RescalState,
                    eps: float = EPS_DEFAULT,
                    sanitize: bool = False,
@@ -119,14 +123,17 @@ def mu_step_sliced(X: jax.Array, state: RescalState,
 
     def body(t, carry):
         R_acc, num, den = carry
-        Xt = jax.lax.dynamic_index_in_dim(X, t, axis=0, keepdims=False)
+        with jax.named_scope("products"):
+            Xt = jax.lax.dynamic_index_in_dim(X, t, axis=0, keepdims=False)
+            XA = Xt @ A                               # (n, k)
         Rt = jax.lax.dynamic_index_in_dim(R_acc, t, axis=0, keepdims=False)
-        XA = Xt @ A                                   # (n, k)
         ATXA = A.T @ XA                               # (k, k)
         Rt = Rt * ATXA / (G @ Rt @ G + eps)           # paper line 9
         R_new = jax.lax.dynamic_update_index_in_dim(R_acc, Rt, t, axis=0)
         XART = XA @ Rt.T                              # line 10
-        XTAR = Xt.T @ (A @ Rt)                        # lines 11-12
+        ARt = A @ Rt                                  # line 11
+        with jax.named_scope("products"):
+            XTAR = Xt.T @ ARt                         # line 12
         num = num + XART + XTAR                       # line 14
         den = den + (Rt @ G @ Rt.T) + (Rt.T @ G @ Rt)  # lines 15-20 (k,k form)
         return R_new, num, den
@@ -199,6 +206,7 @@ def crop_state(state: RescalState, k: int) -> RescalState:
                        step=state.step)
 
 
+@jax.named_scope("mu")
 def masked_mu_step(X: jax.Array, state: RescalState, mask: jax.Array,
                    eps: float = EPS_DEFAULT,
                    schedule: str = "batched",

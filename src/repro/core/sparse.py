@@ -202,12 +202,14 @@ def sparse_products(sp: BCSR, B1: jax.Array, B2: jax.Array, *,
     intermediate); ``use_fused``/``impl`` are its deprecated aliases.
     The default is the two-pass segment-sum oracle."""
     use_fused, impl = _resolve_kernel_opts(policy, use_fused, impl)
-    if use_fused:
-        from repro.kernels import ops                 # lazy: no cycle
-        return ops.bcsr_xa_xta(sp, B1, B2, impl=impl)
-    return spmm(sp, B1), spmm_t(sp, B2)
+    with jax.named_scope("products"):
+        if use_fused:
+            from repro.kernels import ops             # lazy: no cycle
+            return ops.bcsr_xa_xta(sp, B1, B2, impl=impl)
+        return spmm(sp, B1), spmm_t(sp, B2)
 
 
+@jax.named_scope("mu")
 def sparse_mu_step(sp: BCSR, A: jax.Array, R: jax.Array,
                    eps: float = EPS_DEFAULT, *, use_fused: bool = False,
                    impl: str = "auto", policy=None, sanitize: bool = False,
@@ -239,6 +241,7 @@ def sparse_mu_step(sp: BCSR, A: jax.Array, R: jax.Array,
     return A, R
 
 
+@jax.named_scope("mu")
 def masked_sparse_mu_step(sp: BCSR, A: jax.Array, R: jax.Array,
                           mask: jax.Array, eps: float = EPS_DEFAULT, *,
                           use_fused: bool = False, impl: str = "auto",

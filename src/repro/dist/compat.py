@@ -10,9 +10,11 @@ rest of the code reads.  Outside this module, code stays backend-blind:
     (GSPMD propagation decides), the sharding model the engine's
     ``shard_map`` programs are written for; ``jax.make_mesh`` alone
     defaults to explicit axes.
-  * ``capture_compiles`` — counts XLA compilations from the
-    ``jax.log_compiles`` log lines, so the compile-count CI guard
-    (scripts/check_compiles.py) has one parser.
+  * ``capture_compiles`` — counts XLA compilations and persistent-cache
+    reads from the ``jax.log_compiles`` log lines, so the compile-count CI
+    guard (scripts/check_compiles.py) and the tracer have one parser.
+  * ``cache_keyed_on_metadata`` — persistent-cache keys that include the
+    programs' metadata (op_name scopes), a private JAX config state.
   * ``program_memory`` — ``compiled.memory_analysis()`` as one byte
     breakdown, ``None`` when the backend offers none.
   * ``device_memory_stats`` — allocator watermarks exist on TPU and
@@ -31,6 +33,7 @@ import jax
 import jax.sharding
 
 __all__ = [
+    "cache_keyed_on_metadata",
     "capture_compiles",
     "device_memory_stats",
     "donating_jit",
@@ -92,15 +95,20 @@ def make_mesh(axis_shapes: Sequence[int], axis_names: Sequence[str], *,
                          devices=devices)
 
 
-# "Finished XLA compilation of jit(_grid_members) in 0.1 sec"
+# "Finished XLA compilation of jit(_grid_members) in 0.1 sec": logged once
+# per program the backend produced, whether compiled or read from the cache
 _FINISHED_RE = re.compile(r"Finished XLA compilation of jit\(([^)\s]+)\) in")
+# "Persistent compilation cache hit for 'jit__grid_members' with key ...":
+# logged inside the same compile call, on the same thread, just before it
+_CACHE_HIT_RE = re.compile(r"Persistent compilation cache hit for '")
 
 
 class CompileLog:
     """Compile events observed inside a ``capture_compiles`` block.
-    ``events`` holds one traced-function name per XLA compilation (eager
-    jnp ops appear under their primitive names, e.g. ``_pad`` —
-    ``count()`` filters by name so guards can target specific programs)."""
+    ``events`` holds one traced-function name per program XLA produced,
+    compiled or read from the persistent cache (eager jnp ops appear under
+    their primitive names, e.g. ``_pad`` — ``count()`` filters by name so
+    guards can target specific programs)."""
 
     def __init__(self):
         self.events: list[str] = []
@@ -113,54 +121,98 @@ class CompileLog:
         return sum(1 for e in self.events if e in names)
 
 
+# the open capture_compiles blocks, outermost first, as (log, sink)
+_ACTIVE: list[tuple[CompileLog, Any]] = []
+
+
+class _CompileHandler(logging.Handler):
+    """Feeds every open block from JAX's compile log lines.  A cache-hit
+    line marks its thread; that thread's next "Finished" line is then the
+    cache read, so each program counts once, as one kind."""
+
+    def __init__(self):
+        super().__init__(level=logging.DEBUG)
+        self._hit_threads: set[int] = set()
+
+    def emit(self, record: logging.LogRecord) -> None:
+        msg = record.getMessage()
+        if _CACHE_HIT_RE.search(msg):
+            self._hit_threads.add(record.thread)
+            return
+        if not msg.startswith("Finished XLA compilation of "):
+            return
+        hit = record.thread in self._hit_threads
+        self._hit_threads.discard(record.thread)
+        m = _FINISHED_RE.search(msg)
+        if not m:
+            return               # not a jit program (pmap): not counted
+        name = m.group(1)
+        kind = "cache_hit" if hit else "compile"
+        sinks: list = []
+        for log, sink in list(_ACTIVE):
+            log.events.append(name)
+            if sink is not None and sink not in sinks:
+                sinks.append(sink)
+        for sink in sinks:
+            try:
+                sink(name, kind)
+            except Exception:
+                pass     # telemetry must never fail a compile
+
+
 @contextlib.contextmanager
 def capture_compiles(sink=None):
-    """Record every XLA compilation in the block as a ``CompileLog``.
+    """Record every program XLA produces in the block as a ``CompileLog``.
 
     Implemented on ``jax.log_compiles`` + a logging handler rather than
     any private counter — the one place the compile-count CI guard parses
     JAX's log wording.
 
-    ``sink(program, kind)`` is additionally called on every match with
-    kind "finished" — the live-event side channel the tracer uses
-    (``obs.Tracer.compile_event`` has this signature).  Sink exceptions
-    are swallowed: telemetry must never fail a compile.
+    ``sink(program, kind)`` is additionally called once per program, with
+    kind "compile" for a fresh compilation or "cache_hit" for a read from
+    the persistent compilation cache — the live-event side channel the
+    tracer uses (``obs.Tracer.compile_event`` has this signature).  Sink
+    exceptions are swallowed: telemetry must never fail a compile.
+
+    Blocks nest: an inner block sees what it compiles, the outer blocks
+    see it too, and a sink open in several blocks is called once.
     """
     log = CompileLog()
-
-    def _notify(name: str, kind: str) -> None:
-        if sink is not None:
-            try:
-                sink(name, kind)
-            except Exception:
-                pass
-
-    class _Handler(logging.Handler):
-        def emit(self, record: logging.LogRecord) -> None:
-            m = _FINISHED_RE.search(record.getMessage())
-            if m:
-                log.events.append(m.group(1))
-                _notify(m.group(1), "finished")
-
-    handler = _Handler(level=logging.DEBUG)
+    entry = (log, sink)
     logger = logging.getLogger("jax")
-    old_level = logger.level
-    old_propagate = logger.propagate
-    old_handlers = logger.handlers[:]
-    # capture, don't spew: JAX installs its own stderr StreamHandler on
-    # the "jax" logger at import, so swap the handler list rather than
-    # stacking on top of it, and restore verbatim after
-    logger.handlers[:] = [handler]
-    logger.propagate = False
-    if logger.getEffectiveLevel() > logging.WARNING:
-        logger.setLevel(logging.WARNING)     # log_compiles emits at WARNING
+    outermost = not _ACTIVE
+    if outermost:
+        saved = (logger.level, logger.propagate, logger.handlers[:])
+        # capture, don't spew: JAX installs its own stderr StreamHandler on
+        # the "jax" logger at import, so swap the handler list rather than
+        # stacking on top of it, and restore verbatim after
+        logger.handlers[:] = [_CompileHandler()]
+        logger.propagate = False
+        if logger.getEffectiveLevel() > logging.WARNING:
+            logger.setLevel(logging.WARNING)  # log_compiles emits at WARNING
+    _ACTIVE.append(entry)
     try:
         with jax.log_compiles():
             yield log
     finally:
-        logger.handlers[:] = old_handlers
-        logger.setLevel(old_level)
-        logger.propagate = old_propagate
+        _ACTIVE.remove(entry)
+        if outermost:
+            logger.setLevel(saved[0])
+            logger.propagate = saved[1]
+            logger.handlers[:] = saved[2]
+
+
+def cache_keyed_on_metadata():
+    """A context in which the programs compiled, or read from JAX's
+    persistent compilation cache, are keyed on their metadata too.  By
+    default the key strips it, so an executable cached from the same
+    instructions under other op_name scopes (an older build of the
+    program) stands in, and a profile shows its stale op_names.  The
+    price: the metadata holds source locations, call sites included, so
+    a program compiles again once wherever its code, its callers or the
+    checkout's path moved."""
+    from jax._src import config as jax_config
+    return jax_config.compilation_cache_include_metadata_in_key(True)
 
 
 def program_memory(compiled) -> dict[str, Any] | None:

@@ -97,9 +97,11 @@ def _fused_products(Xl, Aj, Ai, cfg: DistRescalConfig):
     from repro.kernels import ops
     m = Xl.shape[0]
     B2 = jnp.broadcast_to(Ai[None], (m,) + Ai.shape)
-    return ops.fused_xa_xtb(Xl, Aj, B2, impl=cfg.kernel_policy.impl)
+    with jax.named_scope("products"):
+        return ops.fused_xa_xtb(Xl, Aj, B2, impl=cfg.kernel_policy.impl)
 
 
+@jax.named_scope("mu")
 def _mu_iter_batched(Xl, Ai, R, cfg: DistRescalConfig):
     """One MU iteration, all m slices per collective (paper Alg. 3 math,
     our O(1)-collective schedule)."""
@@ -112,7 +114,9 @@ def _mu_iter_batched(Xl, Ai, R, cfg: DistRescalConfig):
         XA_loc, XTA_loc = _fused_products(Xl, Aj, Ai, cfg)
         XA = psum_cast(XA_loc, COL_AXIS, cd)                     # line 5
     else:
-        XA = psum_cast(jnp.einsum("mij,jk->mik", Xl, Aj), COL_AXIS, cd)
+        with jax.named_scope("products"):
+            XA_loc = jnp.einsum("mij,jk->mik", Xl, Aj)
+        XA = psum_cast(XA_loc, COL_AXIS, cd)
         XTA_loc = None
 
     # ---- R update (paper lines 6-9), batched over m ----
@@ -133,8 +137,9 @@ def _mu_iter_batched(Xl, Ai, R, cfg: DistRescalConfig):
         # contraction forces XLA to materialize a layout copy of the full X
         # block (verified: temp == bytes(X) in memory_analysis); keeping m
         # as a batch dim costs an (m, k, n_loc) temp instead.
-        XTAR_j = psum_cast(jnp.einsum("mij,mik->mjk", Xl, AR).sum(0),
-                           ROW_AXIS, cd)
+        with jax.named_scope("products"):
+            XTAR_m = jnp.einsum("mij,mik->mjk", Xl, AR)
+        XTAR_j = psum_cast(XTAR_m.sum(0), ROW_AXIS, cd)
     XTAR = diag_broadcast_col_to_row(XTAR_j, cd)                 # lines 12-13
     num = XART + XTAR                                            # line 14
     S = (jnp.einsum("mab,bc,mdc->ad", R, G, R)
@@ -151,6 +156,7 @@ def _mu_iter_batched(Xl, Ai, R, cfg: DistRescalConfig):
     return Ai_new, R
 
 
+@jax.named_scope("mu")
 def _mu_iter_sliced(Xl, Ai, R, cfg: DistRescalConfig):
     """One MU iteration, explicit loop over m slices — the paper's exact
     schedule with per-slice collectives (O(m) psums)."""
@@ -163,13 +169,16 @@ def _mu_iter_sliced(Xl, Ai, R, cfg: DistRescalConfig):
 
     def body(t, carry):
         R_acc, num, S = carry
-        Xt = jax.lax.dynamic_index_in_dim(Xl, t, 0, keepdims=False)
+        with jax.named_scope("products"):
+            Xt = jax.lax.dynamic_index_in_dim(Xl, t, 0, keepdims=False)
         Rt = jax.lax.dynamic_index_in_dim(R_acc, t, 0, keepdims=False)
         if cfg.kernel_policy.use_fused:
             XA_loc, XTA_loc = _fused_products(Xt[None], Aj, Ai, cfg)
             XA = psum_cast(XA_loc[0], COL_AXIS, cd)              # line 5
         else:
-            XA = psum_cast(Xt @ Aj, COL_AXIS, cd)                # line 5
+            with jax.named_scope("products"):
+                XA_loc = Xt @ Aj
+            XA = psum_cast(XA_loc, COL_AXIS, cd)                 # line 5
             XTA_loc = None
         ATXA = psum_cast(Ai.T @ XA, ROW_AXIS, cd)                # line 6
         Rt = Rt * ATXA / (G @ Rt @ G + eps)                      # lines 7-9
@@ -178,7 +187,10 @@ def _mu_iter_sliced(Xl, Ai, R, cfg: DistRescalConfig):
         if XTA_loc is not None:
             XTAR_j = psum_cast(XTA_loc[0] @ Rt, ROW_AXIS, cd)    # line 12
         else:
-            XTAR_j = psum_cast(Xt.T @ (Ai @ Rt), ROW_AXIS, cd)   # lines 11-12
+            AR = Ai @ Rt                                         # line 11
+            with jax.named_scope("products"):
+                XTAR_loc = Xt.T @ AR                             # line 12
+            XTAR_j = psum_cast(XTAR_loc, ROW_AXIS, cd)
         XTAR = diag_broadcast_col_to_row(XTAR_j, cd)             # line 13
         num = num + XART + XTAR                                  # line 14
         S = S + (Rt @ G @ Rt.T) + (Rt.T @ G @ Rt)                # lines 15-20
@@ -198,6 +210,7 @@ def _mu_iter_sliced(Xl, Ai, R, cfg: DistRescalConfig):
     return Ai_new, R
 
 
+@jax.named_scope("mu")
 def _mu_iter_batched_sparse(spl, Ai, R, cfg: DistRescalConfig):
     """Batched MU iteration on a local BCSR block (core/sparse.py).
     Identical collective schedule to the dense batched iteration; with
@@ -217,7 +230,9 @@ def _mu_iter_batched_sparse(spl, Ai, R, cfg: DistRescalConfig):
                                           impl=cfg.kernel_policy.impl)
         XA = psum_cast(XA_loc, COL_AXIS, cd)                     # line 5
     else:
-        XA = psum_cast(spmm(spl, Aj), COL_AXIS, cd)              # line 5
+        with jax.named_scope("products"):
+            XA_loc = spmm(spl, Aj)
+        XA = psum_cast(XA_loc, COL_AXIS, cd)                     # line 5
         XTA_loc = None
 
     ATXA = psum_cast(jnp.einsum("ia,mib->mab", Ai, XA), ROW_AXIS, cd)
@@ -233,7 +248,8 @@ def _mu_iter_batched_sparse(spl, Ai, R, cfg: DistRescalConfig):
                            ROW_AXIS, cd)
     else:
         AR = jnp.einsum("ia,mab->mib", Ai, R)                    # (m, nr, k)
-        XTAR_m = spmm_t(spl, AR)                                 # (m, nr, k)
+        with jax.named_scope("products"):
+            XTAR_m = spmm_t(spl, AR)                             # (m, nr, k)
         XTAR_j = psum_cast(XTAR_m.sum(axis=0), ROW_AXIS, cd)
     XTAR = diag_broadcast_col_to_row(XTAR_j, cd)
     num = XART + XTAR
@@ -251,6 +267,7 @@ def _mu_iter_batched_sparse(spl, Ai, R, cfg: DistRescalConfig):
     return Ai_new, R
 
 
+@jax.named_scope("mu")
 def _mu_iter_sliced_sparse(spl, Ai, R, cfg: DistRescalConfig):
     """Sparse MU iteration with the paper's per-slice schedule.  At
     exabyte-tier n the batched schedule's (m, n/√p, k) dense intermediates
@@ -266,7 +283,9 @@ def _mu_iter_sliced_sparse(spl, Ai, R, cfg: DistRescalConfig):
 
     def body(t, carry):
         R_acc, num, S = carry
-        data_t = jax.lax.dynamic_index_in_dim(spl.data, t, 0, keepdims=True)
+        with jax.named_scope("products"):
+            data_t = jax.lax.dynamic_index_in_dim(spl.data, t, 0,
+                                                  keepdims=True)
         sp_t = BCSR(data=data_t, block_rows=spl.block_rows,
                     block_cols=spl.block_cols, n=spl.n)
         Rt = jax.lax.dynamic_index_in_dim(R_acc, t, 0, keepdims=False)
@@ -275,7 +294,9 @@ def _mu_iter_sliced_sparse(spl, Ai, R, cfg: DistRescalConfig):
                                               impl=cfg.kernel_policy.impl)
             XA = psum_cast(XA_loc[0], COL_AXIS, cd)
         else:
-            XA = psum_cast(spmm(sp_t, Aj)[0], COL_AXIS, cd)
+            with jax.named_scope("products"):
+                XA_loc = spmm(sp_t, Aj)[0]
+            XA = psum_cast(XA_loc, COL_AXIS, cd)
             XTA_loc = None
         ATXA = psum_cast(Ai.T @ XA, ROW_AXIS, cd)
         Rt = Rt * ATXA / (G @ Rt @ G + eps)
@@ -285,7 +306,9 @@ def _mu_iter_sliced_sparse(spl, Ai, R, cfg: DistRescalConfig):
             XTAR_j = psum_cast(XTA_loc[0] @ Rt, ROW_AXIS, cd)
         else:
             AR = Ai @ Rt
-            XTAR_j = psum_cast(spmm_t(sp_t, AR[None])[0], ROW_AXIS, cd)
+            with jax.named_scope("products"):
+                XTAR_loc = spmm_t(sp_t, AR[None])[0]
+            XTAR_j = psum_cast(XTAR_loc, ROW_AXIS, cd)
         XTAR = diag_broadcast_col_to_row(XTAR_j, cd)
         num = num + XART + XTAR
         S = S + (Rt @ G @ Rt.T) + (Rt.T @ G @ Rt)

@@ -43,6 +43,7 @@ from repro.core.clustering import ClusterResult, custom_cluster
 from repro.core.regression import regress_R
 from repro.core.rescal import rel_error
 from repro.core.silhouette import SilhouetteResult, silhouettes
+from repro.dist.compat import cache_keyed_on_metadata, capture_compiles
 from repro.dist.elastic import StragglerMonitor, ensemble_plan
 from repro.obs import trace as obs
 from repro.resilience import RetryPolicy, faults
@@ -163,15 +164,27 @@ def reduce_k(X, cfg: RescalkConfig, k: int, A_ens, R_ens,
     drift.  `X` may be dense or a ``core.sparse.BCSR`` (the regression and
     error swap to their spmm twins; clustering is factor-only either way)."""
     from repro.core.sparse import BCSR, sparse_regress_R, sparse_rel_error
-    clus: ClusterResult = custom_cluster(A_ens, R_ens)
-    sil: SilhouetteResult = silhouettes(clus.A_aligned)
-    if isinstance(X, BCSR):
-        A_med = jax.numpy.asarray(clus.A_median)
-        R_reg = sparse_regress_R(X, A_med, iters=cfg.regress_iters)
-        err = float(sparse_rel_error(X, A_med, R_reg))
-    else:
-        R_reg = regress_R(X, clus.A_median, iters=cfg.regress_iters)
-        err = float(rel_error(X, clus.A_median, R_reg))
+    # each stage's span ends when its result is on the host or ready on the
+    # device: the next stage needs it anyway, so blocking adds no wait
+    with obs.span("reduce/cluster", k=k):
+        clus: ClusterResult = jax.block_until_ready(
+            custom_cluster(A_ens, R_ens))
+    with obs.span("reduce/silhouette", k=k):
+        sil: SilhouetteResult = jax.block_until_ready(
+            silhouettes(clus.A_aligned))
+    sparse = isinstance(X, BCSR)
+    with obs.span("reduce/regress", k=k):
+        if sparse:
+            A_med = jax.numpy.asarray(clus.A_median)
+            R_reg = sparse_regress_R(X, A_med, iters=cfg.regress_iters)
+        else:
+            R_reg = regress_R(X, clus.A_median, iters=cfg.regress_iters)
+        jax.block_until_ready(R_reg)
+    with obs.span("reduce/error", k=k):
+        if sparse:
+            err = float(sparse_rel_error(X, A_med, R_reg))
+        else:
+            err = float(rel_error(X, clus.A_median, R_reg))
     return KResult(
         k=k, s_min=float(sil.s_min), s_mean=float(sil.s_mean),
         rel_err=err, A_median=np.asarray(clus.A_median),
@@ -421,14 +434,19 @@ class SweepScheduler:
             faults.fire("sched/unit", uid=unit.uid, attempt=attempt)
             with obs.span("sched/execute", uid=unit.uid, attempt=attempt):
                 t0 = time.perf_counter()
-                if isinstance(unit, GridChunk):
-                    res = run_sweep_batched(X, unit.cells, self.cfg,
-                                            mesh=self.mesh)
-                else:
-                    res = run_ensemble(X, unit.k, self.cfg,
-                                       members=unit.members,
-                                       mesh=self.mesh, mode=self.mode)
-                jax.block_until_ready(res.A)
+                # keyed on metadata: a cached unit program carries this
+                # build's "mu" / "products" scopes into device profiles
+                with obs.span("sched/dispatch", uid=unit.uid), \
+                        cache_keyed_on_metadata():
+                    if isinstance(unit, GridChunk):
+                        res = run_sweep_batched(X, unit.cells, self.cfg,
+                                                mesh=self.mesh)
+                    else:
+                        res = run_ensemble(X, unit.k, self.cfg,
+                                           members=unit.members,
+                                           mesh=self.mesh, mode=self.mode)
+                with obs.span("sched/wait", uid=unit.uid):
+                    jax.block_until_ready(res.A)
                 timing["dt"] = time.perf_counter() - t0
             return res
 
@@ -467,19 +485,33 @@ class SweepScheduler:
         # + device allocator peak where the backend reports one.  Pure
         # host-side reads — nothing enters any traced program.
         from repro.obs.memory import device_watermark, read_host_memory
+        with obs.span("sched/watermark", uid=unit.uid):
+            peak_host = read_host_memory().get("hwm_bytes")
+            peak_device = device_watermark()
+            fallbacks = kernel_fallbacks() - fb0
         return UnitOutcome(unit=unit, result=res, seconds=dt, reused=False,
                            retries=stats.attempts - 1,
                            attempts=stats.attempts,
                            backoff=stats.backoff_seconds,
                            straggler=straggler,
                            baseline=baseline,
-                           peak_host=read_host_memory().get("hwm_bytes"),
-                           peak_device=device_watermark(),
-                           fallbacks=kernel_fallbacks() - fb0)
+                           peak_host=peak_host,
+                           peak_device=peak_device,
+                           fallbacks=fallbacks)
 
     # -- the sweep ----------------------------------------------------------
 
     def run(self, X) -> RescalkResult:
+        """Run (or resume) the sweep over X.  Under an installed tracer the
+        sweep's compiles and persistent-cache reads become ``xla/compile``
+        events of that tracer."""
+        tracer = obs.current()
+        if tracer is None:
+            return self._run(X)
+        with capture_compiles(sink=tracer.compile_event):
+            return self._run(X)
+
+    def _run(self, X) -> RescalkResult:
         from .ensemble import _is_sharded_bcsr
         cfg = self.cfg
         ks = cfg.ks
@@ -511,18 +543,20 @@ class SweepScheduler:
             # arrays — peak memory stays one k's ensemble, not the sweep's
             if grid:
                 rows = sorted(pending.pop(k), key=lambda t: t[0])
-                A_ens = np.stack([a for _, a, _, _ in rows])
-                R_ens = np.stack([r for _, _, r, _ in rows])
-                errs = np.asarray([e for _, _, _, e in rows])
+                with obs.span("sched/fetch", k=k):
+                    A_ens = np.stack([a for _, a, _, _ in rows])
+                    R_ens = np.stack([r for _, _, r, _ in rows])
+                    errs = np.asarray([e for _, _, _, e in rows])
             else:
                 outs = sorted(pending.pop(k),
                               key=lambda o: o.unit.members[0])
-                A_ens = np.concatenate([np.asarray(o.result.A)
-                                        for o in outs])
-                R_ens = np.concatenate([np.asarray(o.result.R)
-                                        for o in outs])
-                errs = np.concatenate([np.asarray(o.result.errors)
-                                       for o in outs])
+                with obs.span("sched/fetch", k=k):
+                    A_ens = np.concatenate([np.asarray(o.result.A)
+                                            for o in outs])
+                    R_ens = np.concatenate([np.asarray(o.result.R)
+                                            for o in outs])
+                    errs = np.concatenate([np.asarray(o.result.errors)
+                                           for o in outs])
                 for o in outs:
                     o.result = None
                 records.extend(
@@ -560,9 +594,10 @@ class SweepScheduler:
             if grid:
                 # crop each padded cell row to its own k and hand it to
                 # that k's accumulator; the chunk's padded block is dropped
-                A = np.asarray(out.result.A)
-                R = np.asarray(out.result.R)
-                errs = np.asarray(out.result.errors)
+                with obs.span("sched/fetch", uid=unit.uid):
+                    A = np.asarray(out.result.A)
+                    R = np.asarray(out.result.R)
+                    errs = np.asarray(out.result.errors)
                 out.result = None
                 records.append(UnitRecord(
                     uid=unit.uid, k=-1, members=[], seconds=out.seconds,
@@ -591,30 +626,32 @@ class SweepScheduler:
                 reduce_ready(unit.k)
         self._surface_pending_save()
 
-        s_min = np.array([per_k[k].s_min for k in ks])
-        s_mean = np.array([per_k[k].s_mean for k in ks])
-        rel = np.array([per_k[k].rel_err for k in ks])
-        k_opt = criteria.select(self.criterion, ks, s_min, s_mean, rel,
-                                sil_threshold=cfg.sil_threshold)
-        result = RescalkResult(ks=np.asarray(ks), s_min=s_min, s_mean=s_mean,
-                               rel_err=rel, k_opt=k_opt, per_k=per_k)
+        with obs.span("sched/select"):
+            s_min = np.array([per_k[k].s_min for k in ks])
+            s_mean = np.array([per_k[k].s_mean for k in ks])
+            rel = np.array([per_k[k].rel_err for k in ks])
+            k_opt = criteria.select(self.criterion, ks, s_min, s_mean, rel,
+                                    sil_threshold=cfg.sil_threshold)
+            result = RescalkResult(ks=np.asarray(ks), s_min=s_min,
+                                   s_mean=s_mean, rel_err=rel, k_opt=k_opt,
+                                   per_k=per_k)
 
-        meta = {"n_units": len(self.units),
-                "n_retries": sum(r.retries for r in records),
-                "n_stragglers": sum(1 for r in records if r.straggler),
-                "n_kernel_fallbacks": sum(r.kernel_fallbacks
-                                          for r in records)}
-        if self.mesh is not None:
-            meta["mesh"] = {str(a): int(s)
-                            for a, s in dict(self.mesh.shape).items()}
-        self.report = SelectionReport(
-            ks=[int(k) for k in ks], s_min=[float(v) for v in s_min],
-            s_mean=[float(v) for v in s_mean],
-            rel_err=[float(v) for v in rel], k_opt=int(k_opt),
-            criterion=self.criterion, mode=self.mode,
-            n_perturbations=cfg.n_perturbations, units=records, meta=meta)
-        if self.report_path:
-            self.report.save(self.report_path)
+            meta = {"n_units": len(self.units),
+                    "n_retries": sum(r.retries for r in records),
+                    "n_stragglers": sum(1 for r in records if r.straggler),
+                    "n_kernel_fallbacks": sum(r.kernel_fallbacks
+                                              for r in records)}
+            if self.mesh is not None:
+                meta["mesh"] = {str(a): int(s)
+                                for a, s in dict(self.mesh.shape).items()}
+            self.report = SelectionReport(
+                ks=[int(k) for k in ks], s_min=[float(v) for v in s_min],
+                s_mean=[float(v) for v in s_mean],
+                rel_err=[float(v) for v in rel], k_opt=int(k_opt),
+                criterion=self.criterion, mode=self.mode,
+                n_perturbations=cfg.n_perturbations, units=records, meta=meta)
+            if self.report_path:
+                self.report.save(self.report_path)
         if self.verbose and self.ckpt_dir:
             n_reused = self.report.n_reused
             print(f"[sweep] resumed {n_reused}/{len(self.units)} units from "

@@ -1,4 +1,4 @@
-"""repro.analysis: the static-analysis framework, its eight rules against
+"""repro.analysis: the static-analysis framework, its nine rules against
 the bad/ok fixture pairs, the CLI contract, and the runtime sanitizer.
 
 Rule tests run ``run_lint`` directly on one fixture file with one rule
@@ -34,6 +34,7 @@ STEMS = {
     "donation-safety": "donation_safety",
     "nonneg-sanitizer-coverage": "sanitizer_coverage",
     "obs-metrics-coverage": "obs_coverage",
+    "obs-scope-coverage": "scope_coverage",
     "resilience-seam-coverage": "resilience_seams",
 }
 

@@ -2,11 +2,13 @@
 contract (jaxpr identity + no extra compiles), compile-event capture, the
 scheduler/straggler wiring, cost accounting, and the train-loop log fix.
 """
+import contextlib
 import dataclasses
 import functools
 import json
 import logging
 import pathlib
+import re
 
 import jax
 import jax.numpy as jnp
@@ -26,6 +28,7 @@ from repro.obs import trace as obs
 from repro.obs.metrics import (MetricsBuffer, install_buffer,
                                record_metrics, update_ratio)
 from repro.selection import (RescalkConfig, SweepScheduler, run_ensemble)
+from repro.selection.scheduler import plan_sweep
 from repro.selection.report import SelectionReport, UnitRecord
 
 REPO = pathlib.Path(__file__).resolve().parent.parent
@@ -95,7 +98,7 @@ class TestTracer:
         t = obs.Tracer()
         with t.span("ingest/tsv"):
             pass
-        t.compile_event("_batched_members", "finished")
+        t.compile_event("_batched_members", "compile")
         s = t.summarize()
         assert "ingest/tsv" in s and "compile events: 1" in s
 
@@ -127,6 +130,53 @@ class TestModuleChannel:
             assert sw.seconds >= 0
         assert [e["name"] for e in t.events if e["ph"] == "B"] \
             == ["bench/call"]
+
+
+# ---------------------------------------------------------------------------
+# One clock: tracer spans and instants on the profiler's host plane
+# ---------------------------------------------------------------------------
+
+def _host_events(trace_dir):
+    """name -> [(start_ns, end_ns), ...] of a profile's host-plane events."""
+    from jax.profiler import ProfileData
+    path, = pathlib.Path(trace_dir).rglob("*.xplane.pb")
+    out = {}
+    for plane in ProfileData.from_file(str(path)).planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                for ev in line.events:
+                    out.setdefault(ev.name, []).append(
+                        (ev.start_ns, ev.start_ns + ev.duration_ns))
+    return out
+
+
+def _profiled(trace_dir, traced: bool):
+    """A span and an instant inside a window annotation, under a profiler
+    capture, with or without an installed tracer."""
+    with obs.tracing() if traced else contextlib.nullcontext():
+        jax.profiler.start_trace(str(trace_dir))
+        try:
+            with jax.profiler.TraceAnnotation("test/window"):
+                with obs.span("sched/dispatch", uid="u0"):
+                    jnp.ones(3).block_until_ready()
+                obs.event("xla/compile", program="p", kind="compile")
+        finally:
+            jax.profiler.stop_trace()
+    return _host_events(trace_dir)
+
+
+class TestProfilerClock:
+    def test_span_and_instant_land_inside_the_window(self, tmp_path):
+        host = _profiled(tmp_path, traced=True)
+        (w0, w1), = host["test/window"]
+        for name in ("sched/dispatch", "xla/compile"):
+            (s, e), = host[name]
+            assert w0 <= s <= e <= w1
+
+    def test_untraced_span_stays_off_the_profile(self, tmp_path):
+        host = _profiled(tmp_path, traced=False)
+        assert "test/window" in host
+        assert "sched/dispatch" not in host and "xla/compile" not in host
 
 
 # ---------------------------------------------------------------------------
@@ -279,8 +329,126 @@ class TestZeroCostOff:
 
 
 # ---------------------------------------------------------------------------
+# Device scopes: the MU step's phases in the compiled programs' op_name
+# ---------------------------------------------------------------------------
+
+_OP_NAME = re.compile(r'op_name="([^"]*)"')
+_WRAPPED = re.compile(r"^[\w.-]+\((.*)\)$")
+
+
+def _scope_sets(compiled) -> list[set]:
+    """Each op's op_name path as a set of scope names, transform wrappers
+    ('vmap(...)', 'jit(...)') removed."""
+    out = []
+    for path in _OP_NAME.findall(compiled.as_text()):
+        parts = set()
+        for part in path.split("/"):
+            while (m := _WRAPPED.match(part)):
+                part = m.group(1)
+            parts.add(part)
+        out.append(parts)
+    return out
+
+
+def _unit_program(kind):
+    from repro.selection import ensemble
+    keys = jax.random.split(jax.random.PRNGKey(0), 2)
+    if kind == "bcsr":
+        sp = spmod.random_bcsr(jax.random.PRNGKey(0), m=2, n=32, bs=8,
+                               block_density=0.5)
+        return ensemble._batched_members_bcsr.lower(
+            sp, keys, k=3, iters=2, delta=0.03, eps=1e-16)
+    X, _ = _dense_args(n=16)
+    return ensemble._batched_members.lower(
+        X, keys, k=3, iters=2, schedule=kind, init="random", delta=0.03,
+        eps=1e-16)
+
+
+def _engine_program(schedule):
+    from repro.dist import compat
+    from repro.dist.engine import DistRescalConfig, make_mu_step
+    from repro.dist.sharding import COL_AXIS, ROW_AXIS
+    mesh = compat.make_mesh((1, 1), (ROW_AXIS, COL_AXIS))
+    X, st = _dense_args(n=16)
+    step = make_mu_step(mesh, DistRescalConfig(schedule=schedule), iters=2)
+    return step.lower(X, st.A, st.R)
+
+
+class TestDeviceScopes:
+    """Every MU step runs under named_scope("mu") and its reads of the
+    stored operand under "products": the device trace splits a unit
+    iteration by these names (chipbench mu_products_ms / mu_factor_ms)."""
+
+    @pytest.mark.parametrize("program", [
+        pytest.param(lambda: _unit_program("batched"), id="dense-batched"),
+        pytest.param(lambda: _unit_program("sliced"), id="dense-sliced"),
+        pytest.param(lambda: _unit_program("bcsr"), id="bcsr"),
+        pytest.param(lambda: _engine_program("batched"),
+                     id="engine-batched"),
+        pytest.param(lambda: _engine_program("sliced"), id="engine-sliced"),
+    ])
+    def test_program_names_products_and_factor_algebra(self, program):
+        scopes = _scope_sets(program().compile())
+        assert any({"mu", "products"} <= p for p in scopes)
+        assert any("mu" in p and "products" not in p for p in scopes)
+
+    def test_scopes_leave_the_arithmetic_alone(self):
+        """The "mu" scope is metadata: the step compiles to the same
+        instructions, and the same numbers, as its undecorated body."""
+        X, st = _dense_args()
+
+        def hlo(fn):
+            text = jax.jit(fn).lower(X, st).compile().as_text()
+            return [re.sub(r", metadata=\{[^}]*\}", "", ln)
+                    for ln in text.splitlines()
+                    if re.match(r"\s*(ROOT )?%?[\w.-]+ = ", ln)]
+
+        assert hlo(mu_step_batched) == hlo(mu_step_batched.__wrapped__)
+        out = jax.jit(mu_step_batched)(X, st)
+        ref = jax.jit(mu_step_batched.__wrapped__)(X, st)
+        np.testing.assert_array_equal(out.A, ref.A)
+        np.testing.assert_array_equal(out.R, ref.R)
+
+
+# ---------------------------------------------------------------------------
 # Compile-event capture -> tracer
 # ---------------------------------------------------------------------------
+
+@contextlib.contextmanager
+def _persistent_cache(path):
+    """JAX's persistent compilation cache in `path`, every program kept;
+    the process's settings restored after."""
+    from jax.experimental.compilation_cache import compilation_cache
+    keys = ("jax_compilation_cache_dir",
+            "jax_persistent_cache_min_compile_time_secs",
+            "jax_persistent_cache_min_entry_size_bytes")
+    saved = {k: getattr(jax.config, k) for k in keys}
+    try:
+        for k, v in zip(keys, (str(path), 0.0, -1)):
+            jax.config.update(k, v)
+        compilation_cache.reset_cache()
+        yield
+    finally:
+        for k, v in saved.items():
+            jax.config.update(k, v)
+        compilation_cache.reset_cache()
+
+
+def _scoped_program(scope):
+    """A new function object each call, so jit lowers and compiles it
+    again: a second program can only come from the persistent cache."""
+    def obs_cache_probe(x):
+        with jax.named_scope(scope):
+            return jnp.cos(x) * 3 + 1
+    return jax.jit(obs_cache_probe)
+
+
+def _compile_kinds(make) -> list[str]:
+    seen = []
+    with capture_compiles(sink=lambda n, k: seen.append((n, k))):
+        make()(np.ones(7, np.float32)).block_until_ready()
+    return [k for n, k in seen if n == "obs_cache_probe"]
+
 
 class TestCompileEvents:
     def test_sink_feeds_tracer_and_restores_logger(self):
@@ -302,7 +470,7 @@ class TestCompileEvents:
         assert "obs_probe" in names
         kinds = {e["args"]["kind"] for e in tracer.events
                  if e["name"] == "xla/compile"}
-        assert kinds <= {"finished", "compiling"}
+        assert kinds == {"compile"}
 
     def test_sink_exceptions_do_not_break_capture(self):
         def bad_sink(name, kind):
@@ -316,9 +484,48 @@ class TestCompileEvents:
             obs_probe2(jnp.ones(3)).block_until_ready()
         assert log.count("obs_probe2") == 1
 
+    def test_nested_blocks_share_one_handler(self):
+        logger = logging.getLogger("jax")
+        before = (logger.handlers[:], logger.propagate, logger.level)
+        tracer = obs.Tracer()
+
+        @jax.jit
+        def obs_probe3(x):
+            return x + 3
+
+        with capture_compiles(sink=tracer.compile_event) as outer:
+            with capture_compiles(sink=tracer.compile_event) as inner:
+                obs_probe3(jnp.ones(5)).block_until_ready()
+        assert (logger.handlers[:], logger.propagate, logger.level) == before
+        assert outer.count("obs_probe3") == inner.count("obs_probe3") == 1
+        names = [e["args"]["program"] for e in tracer.events
+                 if e["name"] == "xla/compile"]
+        assert names.count("obs_probe3") == 1     # one sink, called once
+
+    def test_persistent_cache_read_is_a_cache_hit(self, tmp_path):
+        """A program read back from the persistent cache is one
+        ``cache_hit``, never also a ``compile``."""
+        with _persistent_cache(tmp_path):
+            kinds = [_compile_kinds(lambda: _scoped_program("a"))
+                     for _ in range(2)]
+        assert kinds == [["compile"], ["cache_hit"]]
+
+    def test_cache_keyed_on_metadata_compiles_new_scopes(self, tmp_path):
+        """The same instructions under other scopes: the default key takes
+        the cached executable (and its stale op_names); keyed on metadata,
+        the program compiles with its own."""
+        from repro.dist.compat import cache_keyed_on_metadata
+        with _persistent_cache(tmp_path):
+            first = _compile_kinds(lambda: _scoped_program("a"))
+            stale = _compile_kinds(lambda: _scoped_program("b"))
+            with cache_keyed_on_metadata():
+                own = _compile_kinds(lambda: _scoped_program("b"))
+        assert (first, stale, own) == (["compile"], ["cache_hit"],
+                                       ["compile"])
+
     def test_compile_events_reach_chrome_export(self, tmp_path):
         t = obs.Tracer()
-        t.compile_event("_grid_members", "finished")
+        t.compile_event("_grid_members", "compile")
         out = tmp_path / "c.json"
         t.export_chrome(str(out))
         evs = json.loads(out.read_text())["traceEvents"]
@@ -351,6 +558,58 @@ class TestSchedulerObservability:
             assert ("sched/execute", rec.uid) in spans
         names = {e["name"] for e in t.events if e["ph"] == "B"}
         assert {"sched/plan", "sched/reduce"} <= names
+
+    def test_unit_programs_are_keyed_on_their_scopes(self, monkeypatch):
+        """Unit programs compile (or are read from the persistent cache)
+        keyed on their metadata, so their "mu" / "products" scopes reach
+        device profiles; nothing else is."""
+        import repro.selection.scheduler as sched_mod
+        run, seen = sched_mod.run_ensemble, []
+
+        def spy(*args, **kwargs):
+            seen.append(
+                jax.config.jax_compilation_cache_include_metadata_in_key)
+            return run(*args, **kwargs)
+
+        monkeypatch.setattr(sched_mod, "run_ensemble", spy)
+        self._run_sweep()
+        assert seen and all(seen)
+        assert not jax.config.jax_compilation_cache_include_metadata_in_key
+
+    @pytest.mark.parametrize("mode", ["batched", "grid"])
+    def test_host_path_spans_nest(self, mode):
+        """The scheduler's host path between and around units is covered:
+        dispatch and wait inside each unit's execute span, the per-unit
+        watermark reads, the fetch of each k's factors, the four stages of
+        each k's reduction inside its reduce span, and the selection."""
+        key = jax.random.PRNGKey(0)
+        X, _, _ = synthetic_rescal(key, n=16, m=2, k=3)
+        cfg = RescalkConfig(k_min=2, k_max=3, n_perturbations=2,
+                            rescal_iters=3)
+        with obs.tracing() as t:
+            SweepScheduler(cfg, mode=mode).run(X)
+        parent, stack = {}, []
+        for e in t.events:
+            if e["ph"] == "B":
+                parent.setdefault(e["name"], set()).add(
+                    stack[-1] if stack else None)
+                stack.append(e["name"])
+            elif e["ph"] == "E":
+                assert stack.pop() == e["name"]
+        assert not stack
+        assert parent["sched/dispatch"] == {"sched/execute"}
+        assert parent["sched/wait"] == {"sched/execute"}
+        for name in ("reduce/cluster", "reduce/silhouette",
+                     "reduce/regress", "reduce/error"):
+            assert parent[name] == {"sched/reduce"}
+        for name in ("sched/watermark", "sched/fetch", "sched/select"):
+            assert parent[name] == {None}
+        begins = [e["name"] for e in t.events if e["ph"] == "B"]
+        n_units = len(plan_sweep(cfg, mode=mode))
+        assert begins.count("sched/dispatch") == n_units
+        assert begins.count("sched/watermark") == n_units
+        assert begins.count("reduce/regress") == len(cfg.ks)
+        assert begins.count("sched/select") == 1
 
     def test_straggler_flagged_in_report(self, capsys):
         # factor 0: every unit after the first exceeds 0 x baseline
